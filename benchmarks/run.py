@@ -37,8 +37,10 @@ def main() -> None:
 
     # Before anything initializes a jax backend: the snn_scale sharded
     # section (and the analysis sweep's mesh programs) want a simulated
-    # multi-device view of the CPU host.
-    from repro.util.env import ensure_host_device_count
+    # multi-device view of the CPU host.  The flag only splits the host
+    # platform; on an accelerator the benches see the real devices.
+    from repro.util.env import enable_compilation_cache, ensure_host_device_count
+    enable_compilation_cache()
     ensure_host_device_count(8)
 
     import jax

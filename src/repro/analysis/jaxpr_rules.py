@@ -6,12 +6,9 @@ callback", "what dtype is this aval", and "is this eqn inside a scan
 body" are direct queries instead of regexes over a pretty-printer whose
 output shifts between jax releases.
 
-Version-compat note: ``ClosedJaxpr``/``Jaxpr``/``JaxprEqn`` moved from
-``jax.core`` to ``jax.extend.core`` across the supported jax range
-(0.4.35 → latest), so nothing here isinstance-checks jaxpr types --
-sub-jaxprs hiding in ``eqn.params`` are recognized *structurally* (an
-object with ``.eqns``, or wrapping one via ``.jaxpr``), which survives
-the module moves.
+Nothing here isinstance-checks jaxpr types: sub-jaxprs hiding in
+``eqn.params`` are recognized *structurally* (an object with ``.eqns``,
+or wrapping one via ``.jaxpr``).
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@ same descriptor, so the checks can never drift from what actually
 launches.  Crucially the BlockSpec index maps in a descriptor are plain
 Python lambdas -- the lint *evaluates them directly* at every concrete
 grid point (substituting worst-case example values for the
-scalar-prefetch operands, e.g. the sentinel row id), instead of parsing
+scalar-prefetch operands), instead of parsing
 ``pallas_call`` jaxpr params whose internal layout changes between jax
 releases.
 
@@ -14,6 +14,9 @@ Rules:
 
 * ``pallas.oob``      -- an index map selects a block outside its operand
   (an out-of-bounds DMA on real hardware: silent garbage or a fault).
+* ``pallas.align``    -- a VMEM block whose last two dimensions are not
+  divisible by the TPU's (8, 128) tiling and not equal to the operand's
+  own: the TPU lowering refuses it, though interpret mode runs it.
 * ``pallas.vmem``     -- estimated VMEM footprint (all tiled blocks
   double-buffered by the pipeline, plus scratch) exceeds the per-platform
   budget.
@@ -33,13 +36,17 @@ from repro.analysis.findings import ERROR, WARNING, Finding
 from repro.kernels.launch_spec import KernelLaunch, Operand
 
 __all__ = [
-    "TPU_VMEM_BUDGET", "check_index_maps", "check_vmem", "check_aliasing",
-    "check_dma_schedule", "check_launch",
+    "TPU_VMEM_BUDGET", "TPU_TILE", "check_index_maps", "check_alignment",
+    "check_vmem", "check_aliasing", "check_dma_schedule", "check_launch",
 ]
 
 # ~16 MiB of VMEM per TPU core; the budget the pipeline's working set
 # must fit in (see DESIGN.md §14 for the estimator model).
 TPU_VMEM_BUDGET = 16 * 1024 * 1024
+
+# (sublanes, lanes): the last two dimensions of a VMEM block must be
+# multiples of these, or span the whole operand dimension.
+TPU_TILE = (8, 128)
 
 
 def _grid_points(grid: Sequence[int]):
@@ -82,6 +89,27 @@ def _oob_for_operand(op: Operand, points, prefetch) -> Optional[Any]:
             if i < 0 or (i + 1) * b > extent:
                 return point, idx
     return None
+
+
+def check_alignment(launch: KernelLaunch, program: str) -> List[Finding]:
+    """Every VMEM block's trailing two dimensions against the (8, 128)
+    tiling -- the rule the TPU lowering enforces at compile time."""
+    out: List[Finding] = []
+    for op in launch.tiled_operands():
+        if op.memory_space != "vmem":
+            continue
+        trailing = zip(op.block_shape[-2:], op.shape[-2:],
+                       TPU_TILE[-len(op.block_shape[-2:]):])
+        for b, extent, tile in trailing:
+            if b % tile and b != extent:
+                out.append(Finding(
+                    rule="pallas.align", severity=ERROR, program=program,
+                    location=f"{launch.name}:{op.name}",
+                    message=f"block {op.block_shape} of operand {op.shape}: "
+                            f"trailing dims must be multiples of "
+                            f"{TPU_TILE} or equal the operand's"))
+                break
+    return out
 
 
 def check_vmem(launch: KernelLaunch, program: str, *,
@@ -215,6 +243,7 @@ def check_launch(launch: KernelLaunch, program: str, *,
                  vmem_budget: int = TPU_VMEM_BUDGET) -> List[Finding]:
     """All kernel-lint rules on one launch descriptor."""
     out = check_index_maps(launch, program)
+    out += check_alignment(launch, program)
     out += check_vmem(launch, program, budget=vmem_budget)
     out += check_aliasing(launch, program)
     out += check_dma_schedule(launch, program)

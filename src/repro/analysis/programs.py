@@ -288,7 +288,7 @@ def kernel_launches() -> Tuple[Tuple[str, KernelLaunch], ...]:
     :mod:`repro.kernels.ops` would pick for a mid-size fabric).  The
     registry name disambiguates variants of the same kernel (the frozen
     and learning tick launches share ``KernelLaunch.name``)."""
-    from repro.kernels.event_dispatch import event_db_launch, event_launch
+    from repro.kernels.event_dispatch import event_db_launch
     from repro.kernels.lif_step import lif_launch
     from repro.kernels.stdp_update import stdp_launch
     from repro.kernels.tick_fused import tick_launch
@@ -305,9 +305,9 @@ def kernel_launches() -> Tuple[Tuple[str, KernelLaunch], ...]:
     return (
         ("lif_step", lif_launch(B=128, K=512, N=256, dtypes=lif_dt)),
         # Frozen pre-masked uniform-delay tick (no c operand), delay
-        # depth 4: the scalar-prefetched read slot steers the DMA.
+        # depth 4: the bridge passes the arriving slot alone (n_read=1).
         ("tick_fused/frozen",
-         tick_launch(B=128, K=512, N=256, n_read=4, dtypes=tick_dt,
+         tick_launch(B=128, K=512, N=256, n_read=1, dtypes=tick_dt,
                      has_c=False, has_delays=False, has_drive=True,
                      write_delay=True, n_full=4)),
         # Learning per-synapse-delay tick: w and c stream separately.
@@ -315,8 +315,6 @@ def kernel_launches() -> Tuple[Tuple[str, KernelLaunch], ...]:
          tick_launch(B=128, K=512, N=256, n_read=4, dtypes=tick_dt,
                      has_c=True, has_delays=True, has_drive=True,
                      write_delay=True, n_full=4)),
-        ("event_dispatch", event_launch(B=8, K=1024, N=256, k_active=128,
-                                        dtypes=ev_dt, has_drive=True)),
         ("event_dispatch_db",
          event_db_launch(B=8, K=1024, N=256, k_active=128, dtypes=ev_dt,
                          has_drive=True)),
@@ -338,8 +336,6 @@ def jit_static_registry():
     return (
         (tick_fused.fused_tick, ("mode",) + dims + ("interpret",)),
         (lif_step.fused_lif_step, ("mode",) + dims + ("interpret",)),
-        (event_dispatch.event_lif_dispatch,
-         ("mode", "block_n", "interpret")),
         (event_dispatch.event_lif_dispatch_db,
          ("mode", "block_n", "interpret")),
         (stdp_update.fused_stdp_step,

@@ -27,16 +27,10 @@ _ATOMS = (str, int, float, bool, bytes, type(None))
 
 
 def _mesh_types() -> tuple:
-    """jax's own static-intended mesh types (version-tolerant)."""
-    try:
-        from jax.sharding import Mesh
-    except ImportError:     # pragma: no cover - ancient jax
-        return ()
-    try:
-        from jax.sharding import AbstractMesh
-        return (Mesh, AbstractMesh)
-    except ImportError:
-        return (Mesh,)
+    """jax's own static-intended mesh types."""
+    from jax.sharding import AbstractMesh, Mesh
+
+    return (Mesh, AbstractMesh)
 
 
 def is_deeply_immutable(value: Any) -> bool:

@@ -77,16 +77,21 @@ DEFAULT_HYSTERESIS = 0.75
 def _platform(platform: Optional[str] = None) -> str:
     if platform is not None:
         return platform
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend()
-    except Exception:  # pragma: no cover
-        return "cpu"
+    return jax.default_backend()
 
 
 def gather_penalty(platform: Optional[str] = None) -> float:
-    return GATHER_PENALTY.get(_platform(platform), GATHER_PENALTY["cpu"])
+    """The calibrated gather penalty of ``platform`` (default: the
+    backend jax runs on).  A platform with no calibration is an error,
+    not the CPU's number: that would plan a chip with the host's costs."""
+    p = _platform(platform)
+    if p not in GATHER_PENALTY:
+        raise ValueError(
+            f"no gather penalty calibrated for platform {p!r} "
+            f"(known: {sorted(GATHER_PENALTY)})")
+    return GATHER_PENALTY[p]
 
 
 def resolve_k_active(n: int, k_active: Optional[int] = None) -> int:

@@ -8,51 +8,41 @@ the ROADMAP cares about (large n, density <= 0.05, rate <= 0.05) almost
 all of that work multiplies zeros.  This kernel is the TPU restatement
 of event dispatch: per batch row, only the (at most ``k_active``)
 *spiking* presynaptic neurons' fan-out slices are ever gathered out of
-HBM, and they are scatter-accumulated into the synaptic-input tile in
-VMEM before the shared LIF epilogue runs in VREGs.
+HBM, and they are accumulated into the synaptic-input tile before the
+shared LIF epilogue runs in VREGs.
 
-Structure (grid ``(B, N/bN, k_active)``, the k axis walking the spike
-list):
+Structure (grid ``(B/8, N/bN)``; :func:`event_lif_dispatch_db`):
 
-* **Spike indices ride in as scalar prefetch.**  The caller
+* **Spike lists ride in as scalar prefetch.**  The caller
   (:func:`repro.kernels.ops.event_lif_step`) extracts the spiking row
-  ids with a tie-stable ``top_k`` -- ascending presynaptic order, so the
-  accumulation visits contributions in the same order as the dense
-  product and stays bit-compatible with the jnp reference.  The ids are
-  *runtime data* in SMEM: the weight operand's index map reads
-  ``idx_ref[b, k]`` and the pipeline DMAs exactly the one ``(1, bN)``
-  fan-out slice that spike needs.  Empty spike slots point at a
-  sentinel all-zero row appended to the weight matrix, so padding
-  contributes nothing without any branch in the kernel body.
-* **Scatter-accumulate in VMEM.**  ``acc += w[idx[b, k]]`` -- the
-  gathered fan-out slice lands in the f32 accumulator tile; across the
-  k grid steps this is the scatter of every active synapse into its
-  postsynaptic neuron's input, at ``B*k_active*N`` adds instead of the
-  dense ``B*K*N`` MACs.  Spikes are binary (the emitted raster), so no
-  value multiply is needed.
-* **Shared LIF epilogue.**  The last k step runs
-  :func:`repro.kernels.lif_step._lif_epilogue` -- the identical
-  threshold/leak/reset/refractory math every other backend uses.
+  ids with a tie-stable ``top_k`` -- ascending presynaptic order -- plus
+  a per-row live count.  Both are *runtime data* in SMEM.  Each row's
+  synaptic input is thus the f32 sum of its spiking weight rows one at
+  a time, in ascending order, from zero (tests hold it to that sum bit
+  for bit).  The jnp reference's dot sums in an order XLA picks, so the
+  two agree bit for bit where every order is exact, e.g. on weights of
+  a dyadic grid.
+* **Double-buffered row gather.**  Each grid step owns an ``(8, bN)``
+  tile: 8 batch rows, the f32 sublane count, so every VMEM block is
+  aligned to the TPU's (8, 128) tiling.  For each of its rows a
+  ``fori_loop`` walks just the ``counts[b]`` live slots, issuing the
+  weight-row DMA for spike k+1 into the alternate VMEM buffer while
+  accumulating spike k (copy start -> wait -> accumulate).  Padding
+  slots are never touched -- a quiet batch row costs zero DMAs -- and
+  the weight matrix stays in HBM (``memory_space=ANY``).
+* **Row-addressable weights.**  The matrix is viewed as ``(K, 1, N)``
+  so a spike's row is an index on an untiled leading axis: the DMA
+  source is a whole ``(1, bN)`` tile, never a one-row slice of an
+  (8, 128)-tiled array (which Mosaic refuses).
+* **Shared LIF epilogue.**  :func:`repro.kernels.lif_step._lif_epilogue`
+  -- the identical threshold/leak/reset/refractory math every other
+  backend uses -- runs once per tile on the 8 accumulated rows.
 
 Overflow (a batch row spiking more than ``k_active`` times) is handled
 by the caller, not here: the bridge detects it and falls back to the
 dense fused kernel (or raises under checkify), so truncation can never
-silently drop spikes.  All shapes must be pre-padded to block multiples
-on the N axis by the caller.
-
-Two variants share the epilogue:
-
-* :func:`event_lif_dispatch` -- the grid kernel above: the k axis is a
-  grid dimension and the *pipeline* DMAs each spike's fan-out slice
-  (sentinel slots still cost a (zero) DMA + add each).
-* :func:`event_lif_dispatch_db` -- the double-buffered compact-list
-  kernel: grid ``(B, N/bN)`` only; a ``fori_loop`` walks just the
-  ``counts[b]`` *live* spike slots, issuing the weight-row DMA for
-  spike k+1 into the alternate VMEM buffer while accumulating spike k
-  (copy start -> accumulate previous -> wait).  Sentinel slots are
-  never touched -- a quiet batch row costs zero DMAs -- and the weight
-  matrix stays in HBM (``memory_space=ANY``), only the gathered
-  ``(1, bN)`` slices ever landing in VMEM.
+silently drop spikes.  N must be pre-padded to a ``block_n`` multiple
+by the caller; the batch is padded to 8 rows here.
 """
 from __future__ import annotations
 
@@ -66,65 +56,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 import numpy as np
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.launch_spec import KernelLaunch, Operand, Scratch
 from repro.kernels.lif_step import _lif_epilogue
 
 DEFAULT_BLOCK_N = 128
-
-
-def _epilogue_operands(B: int, N: int, block_n: int, dtypes: dict,
-                       has_drive: bool, index_arity: int):
-    """The state/param/output operands every event variant shares.
-
-    ``index_arity`` is the number of grid axes the index maps take before
-    the scalar-prefetch operand(s) (grid kernel: 3, db kernel: 2)."""
-    bn = (1, block_n)
-    if index_arity == 3:
-        map_b = lambda b, j, k, s: (b, j)
-        map_p = lambda b, j, k, s: (0, j)
-    else:
-        map_b = lambda b, j, i, c: (b, j)
-        map_p = lambda b, j, i, c: (0, j)
-    state = [Operand("v", (B, N), dtypes["v"], bn, map_b),
-             Operand("r", (B, N), dtypes["r"], bn, map_b)]
-    if has_drive:
-        state.append(Operand("drive", (B, N), dtypes["drive"], bn, map_b))
-    params = [Operand(pname, (1, N), dtypes.get(pname, dtypes["param"]),
-                      bn, map_p)
-              for pname in ("v_th", "leak", "r_ref", "gain", "i_bias",
-                            "v_reset")]
-    outputs = [Operand("v_out", (B, N), dtypes["v"], bn, map_b),
-               Operand("r_out", (B, N), dtypes["r"], bn, map_b),
-               Operand("y_out", (B, N), dtypes["v"], bn, map_b)]
-    return state, params, outputs
-
-
-def event_launch(*, B: int, K: int, N: int, k_active: int, dtypes: dict,
-                 has_drive: bool,
-                 block_n: int = DEFAULT_BLOCK_N) -> KernelLaunch:
-    """Launch descriptor for the grid variant (:func:`event_lif_dispatch`).
-
-    ``K`` is the presynaptic row count *without* the sentinel; the weight
-    operand is (K+1, N) and the lint's prefetch example is an all-sentinel
-    spike list -- the worst-case row index the steered DMA can take.
-    """
-    # The scalar-prefetched spike list steers the DMA: only spiking rows'
-    # fan-out slices ever leave HBM.
-    w_op = Operand("w", (K + 1, N), dtypes["w"], (1, block_n),
-                   lambda b, j, k, s: (s[b, k], j))
-    state, params, outputs = _epilogue_operands(
-        B, N, block_n, dtypes, has_drive, index_arity=3)
-    idx_ex = np.full((B, k_active), K, np.int32)
-    return KernelLaunch(
-        name="event_dispatch",
-        grid=(B, N // block_n, k_active),
-        inputs=tuple([w_op] + state + params),
-        outputs=tuple(outputs),
-        scratch=(Scratch("vmem", (1, block_n), jnp.float32),),
-        num_scalar_prefetch=1,
-        prefetch_example=(idx_ex,),
-    )
+BLOCK_B = 8     # batch rows per tile: the f32 sublane count
 
 
 def db_dma_schedule(nb: int):
@@ -161,22 +97,33 @@ def db_dma_schedule(nb: int):
 def event_db_launch(*, B: int, K: int, N: int, k_active: int, dtypes: dict,
                     has_drive: bool,
                     block_n: int = DEFAULT_BLOCK_N) -> KernelLaunch:
-    """Launch descriptor for the double-buffered compact-list variant
-    (:func:`event_lif_dispatch_db`).  The weight matrix stays in HBM
-    (``memory_space=ANY``); its gathers are manual DMAs described by
-    :func:`db_dma_schedule`."""
-    w_op = Operand("w", (K + 1, N), dtypes["w"], memory_space="any")
-    state, params, outputs = _epilogue_operands(
-        B, N, block_n, dtypes, has_drive, index_arity=2)
-    idx_ex = np.full((B, k_active), K, np.int32)
+    """Launch descriptor for :func:`event_lif_dispatch_db` (``B`` already
+    padded to a :data:`BLOCK_B` multiple).  The ``(K, 1, N)`` weight view
+    stays in HBM (``memory_space=ANY``); its gathers are manual DMAs
+    described by :func:`db_dma_schedule`."""
+    tile = (BLOCK_B, block_n)
+    map_b = lambda b, j, i, c: (b, j)
+    map_p = lambda b, j, i, c: (0, j)
+    w_op = Operand("w", (K, 1, N), dtypes["w"], memory_space="any")
+    state = [Operand("v", (B, N), dtypes["v"], tile, map_b),
+             Operand("r", (B, N), dtypes["r"], tile, map_b)]
+    if has_drive:
+        state.append(Operand("drive", (B, N), dtypes["drive"], tile, map_b))
+    params = [Operand(pname, (1, N), dtypes.get(pname, dtypes["param"]),
+                      (1, block_n), map_p)
+              for pname in ("v_th", "leak", "r_ref", "gain", "i_bias",
+                            "v_reset")]
+    outputs = [Operand("v_out", (B, N), dtypes["v"], tile, map_b),
+               Operand("r_out", (B, N), dtypes["r"], tile, map_b),
+               Operand("y_out", (B, N), dtypes["v"], tile, map_b)]
+    idx_ex = np.full((B, k_active), K - 1, np.int32)
     counts_ex = np.full((B,), k_active, np.int32)
     return KernelLaunch(
         name="event_dispatch_db",
-        grid=(B, N // block_n),
+        grid=(B // BLOCK_B, N // block_n),
         inputs=tuple([w_op] + state + params),
         outputs=tuple(outputs),
-        scratch=(Scratch("vmem", (1, block_n), jnp.float32),
-                 Scratch("vmem", (2, 1, block_n), dtypes["w"]),
+        scratch=(Scratch("vmem", (2, 1, block_n), dtypes["w"]),
                  Scratch("sem_dma", (2,))),
         num_scalar_prefetch=2,
         prefetch_example=(idx_ex, counts_ex),
@@ -184,184 +131,79 @@ def event_db_launch(*, B: int, K: int, N: int, k_active: int, dtypes: dict,
     )
 
 
-def _event_kernel(
-    idx_ref,            # (B, k) i32 in SMEM: spiking row ids (sentinel-padded)
-    *refs,
-    mode: str,
-    has_drive: bool,
-):
-    """One grid step: accumulate one spike's fan-out slice; LIF on the last."""
-    it = iter(refs)
-    w_ref = next(it)
-    v_ref = next(it)
-    r_in_ref = next(it)
-    drive_ref = next(it) if has_drive else None
-    vth_ref, leak_ref, rref_ref, gain_ref, ibias_ref, vreset_ref = (
-        next(it), next(it), next(it), next(it), next(it), next(it))
-    v_out_ref, r_out_ref, y_out_ref = next(it), next(it), next(it)
-    acc_ref = next(it)
-
-    k = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # The index map already steered the DMA to row idx_ref[b, k]: this IS
-    # the event dispatch -- one spiking neuron's fan-out lands on its
-    # postsynaptic tile. Sentinel slots gathered an all-zero row.
-    acc_ref[...] += w_ref[...].astype(jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        v = v_ref[...].astype(jnp.float32)
-        r = r_in_ref[...]
-        drive = drive_ref[...].astype(jnp.float32) if has_drive else None
-        v_new, r_new, spiked = _lif_epilogue(
-            acc_ref[...], v, r, drive,
-            vth_ref[...].astype(jnp.float32),
-            leak_ref[...].astype(jnp.float32),
-            rref_ref[...],
-            gain_ref[...].astype(jnp.float32),
-            ibias_ref[...].astype(jnp.float32),
-            vreset_ref[...].astype(jnp.float32),
-            mode,
-        )
-        v_out_ref[...] = v_new.astype(v_out_ref.dtype)
-        r_out_ref[...] = r_new.astype(r_out_ref.dtype)
-        y_out_ref[...] = spiked.astype(y_out_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("mode", "block_n", "interpret"),
-)
-def event_lif_dispatch(
-    idx: jax.Array,
-    w: jax.Array,
-    v: jax.Array,
-    r: jax.Array,
-    drive: Optional[jax.Array],
-    v_th: jax.Array,
-    leak: jax.Array,
-    r_ref: jax.Array,
-    gain: jax.Array,
-    i_bias: jax.Array,
-    v_reset: jax.Array,
-    *,
-    mode: str = "fixed_leak",
-    block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = False,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Event tick as a single ``pallas_call``.
-
-    Shapes (N pre-padded to ``block_n`` multiples):
-
-    * ``idx``: (B, k_active) i32 -- spiking presynaptic row ids, ascending,
-      padded with the sentinel ``K`` (scalar prefetch).
-    * ``w``: (K + 1, N) effective weights ``W*C`` with an all-zero sentinel
-      row appended at index ``K``.
-    * ``v``/``drive``: (B, N) f32; ``r``: (B, N) i32; params: (N,).
-
-    Returns ``(v', r', y')`` each (B, N).
-    """
-    B, k_active = idx.shape
-    N = w.shape[1]
-    if N % block_n:
-        raise ValueError(f"N={N} must be a multiple of block_n={block_n}")
-    if mode not in ("fixed_leak", "euler"):
-        raise ValueError(f"event dispatch supports fixed_leak|euler, got {mode!r}")
-    has_drive = drive is not None
-
-    launch = event_launch(
-        B=B, K=w.shape[0] - 1, N=N, k_active=k_active,
-        dtypes={"w": w.dtype, "v": v.dtype, "r": r.dtype,
-                "drive": drive.dtype if has_drive else None,
-                "param": v_th.dtype},
-        has_drive=has_drive, block_n=block_n)
-    row = lambda a: a.reshape(1, N)
-    arrays = {"w": w, "v": v, "r": r, "drive": drive,
-              "v_th": row(v_th), "leak": row(leak), "r_ref": row(r_ref),
-              "gain": row(gain), "i_bias": row(i_bias),
-              "v_reset": row(v_reset)}
-
-    kernel = functools.partial(_event_kernel, mode=mode, has_drive=has_drive)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=launch.grid_spec(),
-        out_shape=launch.out_shapes(),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(idx.astype(jnp.int32), *launch.gather(arrays))
-
-
 def _event_db_kernel(
-    idx_ref,            # (B, k) i32 in SMEM: spiking row ids (sentinel-padded)
-    counts_ref,         # (B,) i32 in SMEM: live (non-sentinel) slots per row
+    idx_ref,            # (B, k) i32 in SMEM: spiking row ids, live prefix
+    counts_ref,         # (B,) i32 in SMEM: live slots per row
     *refs,
     mode: str,
     has_drive: bool,
     block_n: int,
 ):
-    """One (b, j) tile: double-buffered walk of the compact spike list."""
+    """One (8-row, bN-column) tile: a double-buffered walk of each row's
+    compact spike list, then the LIF epilogue on all 8 rows."""
     it = iter(refs)
-    w_hbm_ref = next(it)    # full (K+1, N) weights, memory_space=ANY (HBM)
+    w_hbm_ref = next(it)    # (K, 1, N) weights, memory_space=ANY (HBM)
     v_ref = next(it)
     r_in_ref = next(it)
     drive_ref = next(it) if has_drive else None
     vth_ref, leak_ref, rref_ref, gain_ref, ibias_ref, vreset_ref = (
         next(it), next(it), next(it), next(it), next(it), next(it))
     v_out_ref, r_out_ref, y_out_ref = next(it), next(it), next(it)
-    acc_ref = next(it)      # (1, block_n) f32 VMEM
     w_buf_ref = next(it)    # (2, 1, block_n) VMEM: the double buffer
     sem_ref = next(it)      # (2,) DMA semaphores, one per buffer slot
 
-    b = pl.program_id(0)
-    col = pl.program_id(1) * block_n
-    nb = counts_ref[b]
+    col = pl.multiple_of(pl.program_id(1) * block_n, block_n)
 
-    def copy_k(slot, k):
-        # The gather: spike k's fan-out slice for this column tile,
-        # HBM -> VMEM buffer `slot`.
-        return pltpu.make_async_copy(
-            w_hbm_ref.at[pl.ds(idx_ref[b, k], 1), pl.ds(col, block_n)],
-            w_buf_ref.at[slot],
-            sem_ref.at[slot],
-        )
+    def gather_row(b):
+        nb = counts_ref[b]
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+        def copy_k(slot, k):
+            # The gather: spike k's fan-out slice for this column tile,
+            # HBM -> VMEM buffer `slot`.
+            return pltpu.make_async_copy(
+                w_hbm_ref.at[idx_ref[b, k], :, pl.ds(col, block_n)],
+                w_buf_ref.at[slot],
+                sem_ref.at[slot],
+            )
 
-    @pl.when(nb > 0)
-    def _warmup():
-        copy_k(0, 0).start()
+        @pl.when(nb > 0)
+        def _warmup():
+            copy_k(0, 0).start()
 
-    # DMA protocol twin: db_dma_schedule() restates this exact
-    # start/wait/use order in plain Python for the semaphore-pairing
-    # lint -- change both together.
-    def body(k, carry):
-        slot = jax.lax.rem(k, 2)
+        # DMA protocol twin: db_dma_schedule() restates this exact
+        # start/wait/use order in plain Python for the semaphore-pairing
+        # lint -- change both together.
+        def body(k, acc):
+            slot = jax.lax.rem(k, 2)
 
-        @pl.when(k + 1 < nb)
-        def _prefetch():
-            # Start spike k+1's DMA into the other buffer BEFORE waiting
-            # on spike k: the gather overlaps the accumulate.
-            copy_k(1 - slot, k + 1).start()
+            @pl.when(k + 1 < nb)
+            def _prefetch():
+                # Start spike k+1's DMA into the other buffer BEFORE
+                # waiting on spike k: the gather overlaps the accumulate.
+                copy_k(1 - slot, k + 1).start()
 
-        copy_k(slot, k).wait()
-        acc_ref[...] += w_buf_ref[slot].astype(jnp.float32)
-        return carry
+            copy_k(slot, k).wait()
+            return acc + w_buf_ref[slot].astype(jnp.float32)
 
-    # Only the live slots: the loop bound IS the compact-list length, so
-    # sentinel padding costs no DMA, no add -- a quiet row costs nothing.
-    jax.lax.fori_loop(0, nb, body, 0)
+        # Only the live slots: the loop bound IS the compact-list length,
+        # so padding costs no DMA, no add -- a quiet row costs nothing.
+        return jax.lax.fori_loop(0, nb, body,
+                                 jnp.zeros((1, block_n), jnp.float32))
+
+    b0 = pl.program_id(0) * BLOCK_B
+    rows = [gather_row(b0 + i) for i in range(BLOCK_B)]
+    # Assemble the (8, bN) synaptic-input tile row by row (a select per
+    # row: exact, and no unaligned sublane store).
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (BLOCK_B, block_n), 0)
+    acc = jnp.zeros((BLOCK_B, block_n), jnp.float32)
+    for i, row in enumerate(rows):
+        acc = jnp.where(row_id == i, row, acc)
 
     v = v_ref[...].astype(jnp.float32)
     r = r_in_ref[...]
     drive = drive_ref[...].astype(jnp.float32) if has_drive else None
     v_new, r_new, spiked = _lif_epilogue(
-        acc_ref[...], v, r, drive,
+        acc, v, r, drive,
         vth_ref[...].astype(jnp.float32),
         leak_ref[...].astype(jnp.float32),
         rref_ref[...],
@@ -398,18 +240,19 @@ def event_lif_dispatch_db(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Double-buffered compact-spike-list event tick (one ``pallas_call``).
 
-    Same contract as :func:`event_lif_dispatch` plus ``counts``: (B,) i32,
-    the number of live (non-sentinel) slots at the *front* of each row of
-    ``idx`` (the tie-stable top-k packs real spikes first, so the compact
-    list is just the prefix).  The kernel walks only that prefix with a
-    two-slot VMEM buffer: spike k+1's weight-row DMA is in flight while
-    spike k accumulates.  Sentinel slots cost nothing at all (the grid
-    kernel pays a zero-row DMA + add for each).
+    Shapes (N pre-padded to ``block_n`` multiples; any B):
+
+    * ``idx``: (B, k_active) i32 -- spiking presynaptic row ids, the
+      ``counts[b]`` live ones first and ascending (the tie-stable top-k
+      packs real spikes first); the rest are never read.
+    * ``counts``: (B,) i32 live slots per row.
+    * ``w``: (K, N) effective weights ``W*C``.
+    * ``v``/``drive``: (B, N) f32; ``r``: (B, N) i32; params: (N,).
 
     Returns ``(v', r', y')`` each (B, N).
     """
     B, k_active = idx.shape
-    N = w.shape[1]
+    K, N = w.shape
     if N % block_n:
         raise ValueError(f"N={N} must be a multiple of block_n={block_n}")
     if mode not in ("fixed_leak", "euler"):
@@ -418,27 +261,35 @@ def event_lif_dispatch_db(
         raise ValueError(f"counts must be shape ({B},), got {counts.shape}")
     has_drive = drive is not None
 
+    # Pad the batch to whole 8-row tiles: padded rows have no live spikes
+    # (zero DMAs) and are sliced away below.
+    Bp = -(-B // BLOCK_B) * BLOCK_B
+    pad_b = lambda a, value=0: jnp.pad(
+        a, ((0, Bp - B),) + ((0, 0),) * (a.ndim - 1), constant_values=value)
+
     launch = event_db_launch(
-        B=B, K=w.shape[0] - 1, N=N, k_active=k_active,
+        B=Bp, K=K, N=N, k_active=k_active,
         dtypes={"w": w.dtype, "v": v.dtype, "r": r.dtype,
                 "drive": drive.dtype if has_drive else None,
                 "param": v_th.dtype},
         has_drive=has_drive, block_n=block_n)
     row = lambda a: a.reshape(1, N)
-    arrays = {"w": w, "v": v, "r": r, "drive": drive,
+    arrays = {"w": w.reshape(K, 1, N), "v": pad_b(v), "r": pad_b(r, 1),
+              "drive": pad_b(drive) if has_drive else None,
               "v_th": row(v_th), "leak": row(leak), "r_ref": row(r_ref),
               "gain": row(gain), "i_bias": row(i_bias),
               "v_reset": row(v_reset)}
 
     kernel = functools.partial(_event_db_kernel, mode=mode,
                                has_drive=has_drive, block_n=block_n)
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         kernel,
         grid_spec=launch.grid_spec(),
         out_shape=launch.out_shapes(),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(idx.astype(jnp.int32), counts.astype(jnp.int32),
+    )(pad_b(idx.astype(jnp.int32)), pad_b(counts.astype(jnp.int32)),
       *launch.gather(arrays))
+    return tuple(o[:B] for o in outs)

@@ -55,7 +55,7 @@ class Operand:
         from jax.experimental.pallas import tpu as pltpu
 
         if self.memory_space == ANY:
-            return pl.BlockSpec(memory_space=pltpu.ANY)
+            return pl.BlockSpec(memory_space=pl.ANY)
         if self.memory_space == SMEM:
             return pl.BlockSpec(self.block_shape, self.index_map,
                                 memory_space=pltpu.SMEM)
@@ -101,8 +101,7 @@ class KernelLaunch:
     """Everything the ``pallas_call`` and the lint both need to know.
 
     ``prefetch_example`` holds concrete example values for the
-    scalar-prefetch operands (worst-case indices included, e.g. the
-    sentinel row): the analyzer substitutes them for ``s`` when it
+    scalar-prefetch operands (worst-case indices included): the analyzer substitutes them for ``s`` when it
     evaluates index maps.  ``dma_schedule`` is the manual-DMA protocol
     twin for kernels that stream from ``ANY``-space operands (see
     :func:`repro.kernels.event_dispatch.db_dma_schedule`).

@@ -28,8 +28,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.launch_spec import KernelLaunch, Operand, Scratch
 
 DEFAULT_BLOCK_B = 128
@@ -185,7 +185,7 @@ def fused_lif_step(
         kernel,
         grid_spec=launch.grid_spec(),
         out_shape=launch.out_shapes(),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -22,10 +22,7 @@ from repro.kernels import ref as _ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0) -> jax.Array:
@@ -210,6 +207,11 @@ def fused_tick(
     if delays is None and max_delay == 1:
         # Degenerate ring: arriving == previous-tick emissions, no write.
         read = flat(st.lif.y)[:, None, :]
+    elif delays is None:
+        # Uniform ring: only the arriving slot enters the contraction, so
+        # the kernel reads one (B, 1, n) row per tick, not the D-deep ring.
+        read = jax.lax.dynamic_slice_in_dim(
+            st.delay_buf.reshape((-1, max_delay, n)), slots[0], 1, axis=1)
     else:
         read = st.delay_buf.reshape((-1, max_delay, n))
     write = max_delay > 1
@@ -233,7 +235,8 @@ def fused_tick(
     # Padded neurons must never spike: give them refractory lock + huge th.
     r_p = _pad_to(_pad_to(r, 0, bb), 1, bn, value=1)
     drive_p = None if drive is None else pad_b_last(drive, bn)
-    dly_full_p = pad_b_last(read, bn) if write else None
+    dly_full_p = pad_b_last(st.delay_buf.reshape((-1, max_delay, n)),
+                            bn) if write else None
     big = jnp.finfo(jnp.float32).max / 2
     vth_p = _pad_to(params.lif.v_th, 0, bn, value=big)
     leak_p = _pad_to(params.lif.leak, 0, bn)
@@ -410,7 +413,8 @@ def event_synaptic_input(
       slices of ``wc`` and reduce -- ``B*k_active*N`` FLOPs instead of
       ``B*K*N``.  ``jax.lax.top_k`` is tie-stable, so the gathered rows
       come out in ascending presynaptic order and the reduction sums the
-      same nonzero terms in the same order as the dense product.
+      same nonzero terms as the dense product, in the order XLA's dot
+      picks.
     * **fan-in gather** (``fan_in`` given): for every postsynaptic neuron
       read exactly its padded in-edge list -- ``B*N*cap`` FLOPs, no
       data-dependent control flow at all (safe under ``vmap``, which is
@@ -513,18 +517,15 @@ def event_lif_step(
     surrogate: bool = False,
     ext_diag: bool = False,
     use_kernel: Optional[bool] = None,
-    kernel: Optional[str] = None,
     interpret: Optional[bool] = None,
 ) -> LIFState:
     """State-level bridge for ``TickEngine(backend="event")``.
 
-    On TPU the top-k path lowers to a Pallas event-dispatch kernel
-    (:mod:`repro.kernels.event_dispatch`): spike indices ride in as scalar
-    prefetch and only the spiking rows' fan-out slices ever leave HBM.
-    ``kernel`` picks the variant -- ``"db"`` (default on TPU) is the
-    double-buffered compact-spike-list kernel that prefetches row k+1's
-    fan-out slice while accumulating row k and skips sentinel slots
-    entirely; ``"grid"`` is the BlockSpec-steered grid kernel.  On CPU
+    On TPU the top-k path lowers to the Pallas event-dispatch kernel
+    (:mod:`repro.kernels.event_dispatch`): spike lists ride in as scalar
+    prefetch, and a double-buffered loop gathers only the spiking rows'
+    fan-out slices out of HBM, prefetching row k+1's slice while
+    accumulating row k.  On CPU
     (and for the fan-in gather / surrogate paths) the pure-jnp reference
     above *is* the implementation -- XLA already executes the gathers
     natively, so interpret-mode emulation would only add overhead.
@@ -550,48 +551,34 @@ def event_lif_step(
         if surrogate:
             raise ValueError(
                 "event kernel path is inference-only; use the jnp path to train")
-        if kernel is None:
-            kernel = "db"
-        if kernel not in ("db", "grid"):
-            raise ValueError(f"kernel must be 'db' or 'grid', got {kernel!r}")
         from repro.core.dispatch_policy import resolve_k_active
 
         batch_shape = lif_state.v.shape[:-1]
         n = lif_state.v.shape[-1]
         flat = lambda a: a.reshape((-1, a.shape[-1]))
         s = flat(spikes)
-        B, K = s.shape
-        k = resolve_k_active(K, k_active)
+        k = resolve_k_active(s.shape[-1], k_active)
         drive = _drive_of(None if ext is None else flat(ext))
         vals, idx = jax.lax.top_k(s, k)
-        # Padded slots point at the sentinel zero row appended below.
-        idx = jnp.where(vals > 0, idx, K).astype(jnp.int32)
         # Per-row live-slot count: top_k packs the 1.0s first, so the
         # first counts[b] slots are the real spiking rows (ascending) and
-        # the double-buffered kernel never touches the sentinel tail.
+        # the kernel never reads the tail.
         counts = jnp.sum(vals > 0, axis=-1).astype(jnp.int32)
         bn = _pick_block(n, _ev_kernel.DEFAULT_BLOCK_N, 128)
         pad_n = lambda a, v=0: _pad_to(a, a.ndim - 1, bn, value=v)
-        wc_p = pad_n(jnp.concatenate(
-            [wc, jnp.zeros((1, wc.shape[1]), wc.dtype)], axis=0))
-        v_p = pad_n(flat(lif_state.v))
-        r_p = pad_n(flat(lif_state.r), 1)   # padded neurons: refractory lock
-        drive_p = None if drive is None else pad_n(drive)
         big = jnp.finfo(jnp.float32).max / 2
         lp = params.lif
 
         def event(_):
-            dispatch = (_ev_kernel.event_lif_dispatch_db if kernel == "db"
-                        else _ev_kernel.event_lif_dispatch)
-            kw = dict(counts=counts) if kernel == "db" else {}
-            v_new, r_new, y = dispatch(
-                idx, wc_p, v_p, r_p, drive_p,
+            v_new, r_new, y = _ev_kernel.event_lif_dispatch_db(
+                idx, pad_n(wc), pad_n(flat(lif_state.v)),
+                pad_n(flat(lif_state.r), 1),    # padded neurons: refractory
+                None if drive is None else pad_n(drive),
                 _pad_to(lp.v_th, 0, bn, value=big), _pad_to(lp.leak, 0, bn),
                 _pad_to(lp.r_ref, 0, bn), _pad_to(lp.gain, 0, bn),
                 _pad_to(lp.i_bias, 0, bn), _pad_to(lp.v_reset, 0, bn),
-                mode=mode, block_n=bn,
+                counts=counts, mode=mode, block_n=bn,
                 interpret=not _on_tpu() if interpret is None else interpret,
-                **kw,
             )
             return v_new[:, :n], r_new[:, :n], y[:, :n]
 

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 DEFAULT_BLOCK_B = 128
 DEFAULT_BLOCK_N = 128
@@ -75,7 +74,7 @@ def spike_matmul(
         out_specs=pl.BlockSpec((block_b, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_b, block_n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -49,8 +49,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.launch_spec import KernelLaunch, Operand, Scratch
 
 DEFAULT_BLOCK_B = 128
@@ -227,7 +227,7 @@ def fused_stdp_step(
         kernel,
         grid_spec=launch.grid_spec(),
         out_shape=launch.out_shapes(),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -13,14 +13,15 @@ whole circuit.
 Structure (grid ``(B/bB, N/bN, K/bK)``, K the presynaptic contraction
 axis, K-steps accumulating into a VMEM f32 scratch):
 
-* **Delay-line read at zero cost.** The circular read pointer
-  ``slot = tick % D`` is a *runtime scalar*, so the slot cannot be baked
-  into a BlockSpec constant without retracing every tick. It rides in as
-  a scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``): the
-  index map of the delay-buffer operand reads ``slots_ref[0]`` and the
-  pipeline DMAs exactly the one ``(bB, 1, bK)`` slot tile the tick
-  needs -- the read costs the same HBM traffic as a plain spike-vector
-  load, and changing ``tick`` never recompiles.
+* **Delay-line read without a retrace.** The circular pointers
+  ``tick % D`` / ``(tick+1) % D`` are *runtime scalars* riding in as
+  scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``), so
+  changing ``tick`` never recompiles.  With a uniform delay the caller
+  dynamic-slices the arriving slot out of the ring and passes a
+  ``(B, 1, K)`` operand: the read costs one spike row per tick, like a
+  plain spike-vector load.  (A ``(bB, 1, bK)`` block steered at the
+  slot of the whole ``(B, D, K)`` ring would be one row out of D, not
+  aligned to the TPU's (8, 128) tiling, and does not compile.)
 * **Masked accumulation.** Same as :mod:`lif_step`: ``w*c`` fused per
   tile in VMEM (the mux that routes a zero, at zero bandwidth), double-
   buffered by the Pallas pipeline across K steps. The frozen path passes
@@ -51,10 +52,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 import numpy as np
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.launch_spec import KernelLaunch, Operand, Scratch
 from repro.kernels.lif_step import _lif_epilogue
 
@@ -108,8 +109,8 @@ def _tick_kernel(
         wc = wc * c_ref[...].astype(jnp.float32)
 
     if not has_delays:
-        # Uniform delay: the BlockSpec index map already steered the DMA at
-        # the scalar-prefetched read slot; the tile is (bB, 1, bK).
+        # Uniform delay: the caller passed only the arriving slot; the
+        # tile is (bB, 1, bK).
         s = dly_read_ref[:, 0, :].astype(jnp.float32)
         acc_ref[...] += jnp.dot(s, wc, preferred_element_type=jnp.float32)
     else:
@@ -189,17 +190,11 @@ def tick_launch(
     map_kn = lambda i, j, k, s: (k, j)
     map_param = lambda i, j, k, s: (0, j)
 
-    if has_delays:
-        # Full history tile: every slot participates in the contraction.
-        read = Operand("dly_read", (B, n_read, K), dtypes["dly_read"],
-                       (block_b, n_read, block_k),
-                       lambda i, j, k, s: (i, 0, k))
-    else:
-        # The scalar-prefetched circular pointer steers the DMA: only the
-        # slot arriving this tick ever leaves HBM.
-        read = Operand("dly_read", (B, n_read, K), dtypes["dly_read"],
-                       (block_b, 1, block_k),
-                       lambda i, j, k, s: (i, s[0], k))
+    # Per-synapse delays contract every slot of the (bB, D, bK) history
+    # tile; a uniform ring arrives as its one slot (n_read == 1).
+    read = Operand("dly_read", (B, n_read, K), dtypes["dly_read"],
+                   (block_b, n_read, block_k),
+                   lambda i, j, k, s: (i, 0, k))
 
     inputs = [read,
               Operand("w", (K, N), dtypes["w"], kn, map_kn)]
@@ -278,8 +273,9 @@ def fused_tick(
     Shapes (pre-padded to block multiples):
 
     * ``slots``: (2,) i32 -- ``[tick % D, (tick+1) % D]`` (scalar prefetch).
-    * ``dly_read``: (B, Dr, K) spike history. Uniform-delay reads take the
-      one prefetched slot; per-synapse delays take all ``Dr`` slots.
+    * ``dly_read``: (B, Dr, K) spike history: the arriving slot alone
+      (``Dr == 1``) for a uniform delay; all ``Dr`` slots for
+      per-synapse delays.
     * ``w``: (K, N) weights -- pre-masked ``W*C`` when ``c`` is None.
     * ``c``: (K, N) connection mask or None (frozen pre-masked path).
     * ``delays``: (K, N) i32 in ``[1, Dr]`` or None (uniform 1-tick delay).
@@ -331,7 +327,7 @@ def fused_tick(
         kernel,
         grid_spec=launch.grid_spec(),
         out_shape=launch.out_shapes(),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,9 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any jax import: jax locks the device
 # count at first initialization, and the dry-run needs 512 placeholder CPU
-# devices to build the production meshes. (Only the dry-run does this --
-# smoke tests and benchmarks see the real single device.)
+# devices to build the production meshes. It is a CPU-only tool: pinning
+# the platform (inherited by the per-cell child processes) keeps it, and
+# every child, off an accelerator another process may hold.
 
 """Multi-pod dry-run: AOT-lower + compile every (arch x shape x mesh) cell.
 
@@ -124,7 +126,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             if hasattr(ma, k)
         }
         print("memory_analysis:", mem)
-        ca = hlo_cost.cost_dict(compiled.cost_analysis())
+        ca = compiled.cost_analysis() or {}
         print("cost_analysis: flops=%s bytes=%s" % (
             ca.get("flops"), ca.get("bytes accessed")))
 

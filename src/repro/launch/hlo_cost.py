@@ -46,23 +46,6 @@ _HEADER_RE = re.compile(r"^(ENTRY\s+)?%([\w.\-]+)\s*\(.*\{\s*$")
 Shape = Tuple[str, Tuple[int, ...]]
 
 
-def cost_dict(cost_analysis) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across jaxlib versions.
-
-    Older jaxlib returns a flat dict; newer returns a one-element list of
-    dicts (one per program).  Returns {} for None/empty so callers can
-    ``.get()`` unconditionally.
-    """
-    if cost_analysis is None:
-        return {}
-    if isinstance(cost_analysis, dict):
-        return cost_analysis
-    if isinstance(cost_analysis, (list, tuple)):
-        return cost_analysis[0] if cost_analysis and isinstance(
-            cost_analysis[0], dict) else {}
-    return {}
-
-
 def _nbytes(sh: Shape) -> int:
     dt, dims = sh
     return _DTYPE_BYTES.get(dt, 4) * (math.prod(dims) if dims else 1)
@@ -263,6 +246,30 @@ def _entry_name(comps: Dict[str, Computation], entry: Optional[str]) -> str:
         if name not in referenced:
             return name
     return next(iter(comps))
+
+
+_MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
+_OP_NAME_RE = re.compile(r'metadata=\{op_name="([^"]*)"')
+_JIT_SCOPE_RE = re.compile(r"jit\((\w+)\)")
+
+
+def mosaic_kernels(hlo: str) -> Dict[str, int]:
+    """Mosaic (Pallas on TPU) kernel call sites in a compiled program's
+    text, as kernel name -> number of call sites.  The name is that of
+    the innermost jitted function around the ``pallas_call`` (every
+    kernel entry point in :mod:`repro.kernels` is jitted, e.g.
+    ``event_lif_dispatch_db``), else the custom call's instruction name.
+    Empty for a program compiled off the TPU, where Pallas kernels run
+    interpreted."""
+    out: Dict[str, int] = defaultdict(int)
+    for line in hlo.splitlines():
+        m = _OP_RE.match(line)
+        if not (m and _MOSAIC_TARGET in line):
+            continue
+        op_name = _OP_NAME_RE.search(line)
+        scopes = _JIT_SCOPE_RE.findall(op_name.group(1)) if op_name else []
+        out[scopes[-1] if scopes else re.sub(r"\.\d+$", "", m.group(1))] += 1
+    return dict(out)
 
 
 def analyze(hlo: str) -> CostSummary:

@@ -17,12 +17,8 @@ from repro.parallel.sharding import AxisRules, BASE_RULES, fsdp_overrides, multi
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    # older jaxlib: no explicit axis types (Auto is the implicit default)
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_snn_mesh(n_devices: int | None = None, axis: str = "model") -> jax.sharding.Mesh:
@@ -42,11 +38,8 @@ def make_snn_mesh(n_devices: int | None = None, axis: str = "model") -> jax.shar
             "devices visible (set XLA_FLAGS="
             "--xla_force_host_platform_device_count before jax init, "
             "e.g. via repro.util.env.ensure_host_device_count)")
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh((n_devices,), (axis,),
-                             axis_types=(axis_type.Auto,))
-    return jax.make_mesh((n_devices,), (axis,))
+    return jax.make_mesh((n_devices,), (axis,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def make_rules(

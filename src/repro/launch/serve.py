@@ -31,7 +31,7 @@ import dataclasses
 import functools
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +42,7 @@ from repro.core.engine import EngineOptions
 from repro.deprecation import warn_deprecated
 from repro.models import model as M
 from repro.obs import MetricsRegistry, log_event, profile, span
+from repro.util.env import enable_compilation_cache
 
 
 @dataclasses.dataclass
@@ -405,6 +406,8 @@ class SNNServer:
         self._compiles: Dict[str, int] = {}   # per-program, TRACE time only
         self._runs: Dict[str, object] = {}
         self._chunk_runs: Dict[tuple, object] = {}
+        # (backend, chunk) -> shapes of the first dispatched argument list
+        self._chunk_arg_specs: Dict[tuple, tuple] = {}
         self._fresh_zeros = None
         self._tenant_obs: Dict[str, Dict] = {}  # accumulated telemetry
         self.registry = registry if registry is not None else MetricsRegistry()
@@ -477,6 +480,16 @@ class SNNServer:
             self._chunk_runs[key] = jax.jit(
                 functools.partial(self._chunk_fn, backend, int(chunk)))
         return self._chunk_runs[key]
+
+    def chunk_program_text(self, backend: str,
+                           chunk: Optional[int] = None) -> str:
+        """Compiled text of the resident chunk program for ``backend``,
+        lowered for the argument shapes :meth:`serve_continuous` first
+        dispatched it with (a ``KeyError`` if it never ran).  For checks
+        of what the program holds, e.g. which Pallas kernels it calls."""
+        key = (backend, int(self.chunk_ticks if chunk is None else chunk))
+        return self._chunk_runs[key].lower(
+            *self._chunk_arg_specs[key]).compile().as_text()
 
     # -- tenant registry ---------------------------------------------------
 
@@ -1184,6 +1197,11 @@ class SNNServer:
                     jnp.asarray(budget), counts_acc)
             if backend == "event":
                 args += (fan_idx_s, fan_mask_s)
+            if (backend, chunk) not in self._chunk_arg_specs:
+                self._chunk_arg_specs[(backend, chunk)] = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype, sharding=a.sharding,
+                        weak_type=a.weak_type), args)
             # Dispatch-side timing: counts stay on device, so this span
             # does NOT wait for the chunk to execute -- consecutive
             # chunks pipeline, and the device queue only drains at a
@@ -1272,16 +1290,23 @@ def make_demo_requests(server: SNNServer, names: List[str], n_requests: int,
     return reqs
 
 
-def serve_snn_main(cfg, args) -> Dict:
-    # Dense default program + event program for sparse tenants: tenants at
-    # or below 20% density pick event dispatch per slot (DESIGN.md §10).
+def make_snn_server(cfg, slots: int) -> Tuple[SNNServer, List[str]]:
+    """The serve CLI's fabric for an SNN config: the server plus its
+    resident demo tenants (at least 8, one of them plastic).
+
+    Dense default program + event program for sparse tenants: tenants at
+    or below 20% density pick event dispatch per slot (DESIGN.md §10)."""
     backend = "jnp" if cfg.snn_backend == "event" else cfg.snn_backend
-    server = SNNServer(n_max=cfg.n_neurons, slots=args.slots,
+    server = SNNServer(n_max=cfg.n_neurons, slots=slots,
                        max_ticks=cfg.n_ticks, mode=cfg.snn_mode,
                        backend=backend, event_density=0.2,
                        chunk_ticks=max(
                            1, min(cfg.snn_chunk_ticks, cfg.n_ticks)))
-    names = make_demo_tenants(server, max(8, args.slots))
+    return server, make_demo_tenants(server, max(8, slots))
+
+
+def serve_snn_main(cfg, args) -> Dict:
+    server, names = make_snn_server(cfg, args.slots)
     print(f"serving SNN fabric n_max={server.n_max}: {len(names)} resident "
           f"tenants, {args.slots} slots, {args.requests} requests")
     reqs = make_demo_requests(server, names, max(args.requests, len(names)))
@@ -1342,12 +1367,17 @@ def serve_sharded_main(cfg, args) -> Dict:
     n, n_dev = cfg.n_neurons, cfg.snn_mesh
     have = ensure_host_device_count(n_dev)
     if have < n_dev:
+        platform = jax.default_backend()
+        if platform != "cpu":
+            raise SystemExit(
+                f"config {cfg.name!r} shards its fabric over {n_dev} "
+                f"{platform} chips; this host has {have}")
         raise SystemExit(
             f"config {cfg.name!r} wants a {n_dev}-device mesh but jax sees "
-            f"{have} device(s) and its backend is already initialized; "
-            f"re-run with XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{n_dev} (or let repro.util.env.ensure_host_device_count run "
-            f"before anything touches jax)")
+            f"{have} CPU device(s) and its backend is already initialized; "
+            f"to simulate the mesh on the host, re-run with "
+            f"JAX_PLATFORMS=cpu XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_dev}")
     mesh = make_snn_mesh(n_dev)
 
     backend = cfg.snn_backend
@@ -1387,6 +1417,11 @@ def serve_sharded_main(cfg, args) -> Dict:
 
     carry = TickCarry(state=SNNState.zeros((), n),
                       telem=TickTelemetry.zeros(()))
+    # ... and commit it to the mesh as each chunk's output is: an
+    # array's mesh is part of its abstract type, so an uncommitted seed
+    # would trace the chunk program a second time on the first hand-off.
+    carry = snn_sharding.place(
+        carry, snn_sharding.carry_specs(rules, carry), mesh)
 
     chunk_ticks = max(1, cfg.snn_chunk_ticks)
     n_chunks = max(2, args.requests)
@@ -1408,9 +1443,13 @@ def serve_sharded_main(cfg, args) -> Dict:
     carry, raster = chunk_fn(params, carry, _ext())      # warmup / compile
     jax.block_until_ready(raster)
     warm_traces = traces
+    # Emitted spikes, summed on device over every chunk the telemetry
+    # accumulator also saw (the warmup chunk included).
+    spikes_out = raster.sum()
     t0 = time.perf_counter()
     for _ in range(n_chunks):
         carry, raster = chunk_fn(params, carry, _ext())
+        spikes_out = spikes_out + raster.sum()
     jax.block_until_ready(raster)
     dt = time.perf_counter() - t0
 
@@ -1424,6 +1463,7 @@ def serve_sharded_main(cfg, args) -> Dict:
         "ticks_per_s": ticks / dt,
         "synops_per_s": ticks / dt * float(n) * float(n),
         "recompiles_after_warmup": traces - warm_traces,
+        "spikes_out": float(spikes_out),
     }
     for k, v in stats.items():
         print(f"{k}: {v}")
@@ -1437,7 +1477,7 @@ def serve_sharded_main(cfg, args) -> Dict:
                       sort_keys=True)
         print(f"wrote metrics JSON to {out}")
     assert stats["recompiles_after_warmup"] == 0, "chunk loop recompiled!"
-    return stats
+    return {**stats, "telemetry": tel}
 
 
 def main(argv=None):
@@ -1458,6 +1498,7 @@ def main(argv=None):
                     help="dump the metrics registry as JSON to PATH "
                          "(SNN server only)")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     bundle = get_bundle(args.arch)
     cfg = bundle.smoke if args.smoke else bundle.model

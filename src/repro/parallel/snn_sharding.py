@@ -48,15 +48,16 @@ from repro.parallel.sharding import AxisRules, BASE_RULES
 
 
 def shard_map_fn(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions (module move + kwarg rename)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # jax < 0.6
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` without varying-manual-axes checking.
+
+    The tick scan's carry mixes replicated leaves (telemetry, the tick
+    counter) with per-shard ones, and the collectives that keep the
+    replicated leaves identical on every shard (the spike all-gather,
+    :func:`combine_telemetry`) are ones the checker cannot see through:
+    with ``check_vma`` on, the scan rejects a carry whose input is
+    replicated and whose output is typed as varying."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def snn_rules(mesh: Optional[Mesh] = None, axis: str = "model") -> AxisRules:

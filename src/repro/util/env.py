@@ -21,6 +21,28 @@ from __future__ import annotations
 import os
 
 _FLAG = "--xla_force_host_platform_device_count"
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# <repo>/.jax_cache: a fixed path, because the path is part of what the
+# cache is keyed on -- a directory that moved between runs never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def enable_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and
+    this sets no other directory.  Otherwise the cache goes to
+    :data:`DEFAULT_CACHE_DIR`.  Call it from a ``main()``, never at
+    import: a library import must not redirect the caller's cache.
+    """
+    if os.environ.get(_CACHE_ENV):
+        return os.environ[_CACHE_ENV]
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR
 
 
 def ensure_host_device_count(n: int) -> int:

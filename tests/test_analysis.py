@@ -84,7 +84,7 @@ class TestDtypeTeeth:
         assert jaxpr_rules.check_dtype_discipline(cj, "fixture") == []
 
     def test_f64_fires_when_x64_enabled(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             cj = jaxpr_rules.closed_jaxpr_of(
                 lambda x: x + 1.0, jnp.zeros((2,), jnp.float64))
         rules = _rules(jaxpr_rules.check_dtype_discipline(cj, "fixture"))
@@ -249,8 +249,8 @@ class TestPallasTeeth:
             pallas_rules.check_index_maps(launch, "fixture"))
 
     def test_sentinel_row_prefetch_is_in_bounds(self):
-        """The event kernel's worst case -- every index the sentinel row
-        K -- must lint clean (the (K+1, N) operand exists for it)."""
+        """A prefetch-steered row gather at its worst case -- every
+        index the operand's last row -- must lint clean."""
         launch = _tiny_launch(
             inputs=(Operand("w", (9, 128), F32, (1, 128),
                             lambda i, s: (s[i], 0)),),
@@ -276,6 +276,26 @@ class TestPallasTeeth:
             input_output_aliases={1: 0})
         assert "pallas.alias" in _rules(
             pallas_rules.check_aliasing(launch, "fixture"))
+
+    @pytest.mark.parametrize("shape,block", [
+        ((8, 256), (1, 128)),      # one row of an 8-row batch operand
+        ((64, 200), (8, 100)),     # lane block not a multiple of 128
+    ])
+    def test_misaligned_block_fires(self, shape, block):
+        launch = _tiny_launch(inputs=(
+            Operand("x", shape, F32, block, lambda i: (0, 0)),))
+        assert "pallas.align" in _rules(
+            pallas_rules.check_alignment(launch, "fixture"))
+
+    @pytest.mark.parametrize("shape,block", [
+        ((16, 256), (8, 128)),     # whole (8, 128) tiles
+        ((1, 256), (1, 128)),      # a (1, N) parameter row: full extent
+        ((3, 74), (3, 74)),        # the whole operand
+    ])
+    def test_aligned_block_passes(self, shape, block):
+        launch = _tiny_launch(inputs=(
+            Operand("x", shape, F32, block, lambda i: (0, 0)),))
+        assert pallas_rules.check_alignment(launch, "fixture") == []
 
     @pytest.mark.parametrize("ops,rule", [
         ([("start", 0, 0), ("use", 0, 0)], "pallas.dma.use_before_wait"),

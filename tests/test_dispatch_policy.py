@@ -90,6 +90,11 @@ class TestKneeModel:
         assert knee_spikes(1024, platform="tpu") == 512   # n / 2
         assert knee_spikes(8, platform="cpu") == 1        # floored
 
+    def test_uncalibrated_platform_raises(self):
+        # A platform without a calibration must not plan with the CPU's.
+        with pytest.raises(ValueError, match="no gather penalty"):
+            knee_spikes(1024, platform="neuron")
+
     def test_is_diagonal(self):
         assert is_diagonal(np.eye(8))
         assert is_diagonal(np.diag(np.arange(1.0, 9.0)))

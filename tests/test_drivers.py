@@ -53,3 +53,22 @@ def test_serve_greedy_deterministic():
     s2 = serve_mod.main(["--arch", "smollm-135m", "--smoke", "--requests", "2",
                          "--max-new", "4", "--slots", "2", "--max-len", "32"])
     assert s1["outputs"] == s2["outputs"]
+
+
+def test_compilation_cache_dir(monkeypatch, tmp_path):
+    """The drivers' cache helper: with ``JAX_COMPILATION_CACHE_DIR`` set
+    it adds no other directory; without it the cache goes to the repo's
+    fixed ``.jax_cache``."""
+    from repro.util import env
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert env.enable_compilation_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert env.enable_compilation_cache() == env.DEFAULT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == env.DEFAULT_CACHE_DIR
+        assert env.DEFAULT_CACHE_DIR.endswith(".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

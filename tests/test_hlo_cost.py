@@ -34,7 +34,7 @@ class TestKnownCounts:
             return jax.lax.scan(lambda c, w: (c @ w, None), x, ws)[0]
 
         c = _compile(scanned, x, ws)
-        raw = hlo_cost.cost_dict(c.cost_analysis()).get("flops")
+        raw = c.cost_analysis().get("flops")
         s = hlo_cost.analyze(c.as_text())
         assert s.flops == pytest.approx(n * 2 * D**3, rel=1e-6)
         # the motivating discrepancy: raw counts the body once
@@ -130,3 +130,25 @@ class TestCollectives:
         c = _compile(lambda a, b: a @ b, x, x)
         s = hlo_cost.analyze(c.as_text())
         assert s.dot_bytes == pytest.approx(3 * D * D * 4, rel=1e-6)
+
+
+class TestMosaicKernels:
+    def test_named_by_innermost_jit_scope(self):
+        """A vmapped kernel's custom call is named after a wrapper
+        (``closed_call``); the jit scope in its op_name names the kernel."""
+        hlo = "\n".join([
+            '  %closed_call.11 = (f32[8,128]{1,0}) custom-call(%p.1), '
+            'custom_call_target="tpu_custom_call", metadata={op_name='
+            '"jit(run)/vmap()/while/body/jit(fused_tick)/while/body/'
+            'closed_call/pallas_call" stack_frame_id=41}',
+            '  ROOT %event_lif_dispatch_db.1 = (f32[8,128]{1,0}) '
+            'custom-call(%p.2), custom_call_target="tpu_custom_call"',
+            '  %cc.3 = f32[8]{0} custom-call(%p.3), '
+            'custom_call_target="Sharding", metadata={op_name="jit(f)"}',
+        ])
+        assert hlo_cost.mosaic_kernels(hlo) == {"fused_tick": 1,
+                                                "event_lif_dispatch_db": 1}
+
+    def test_cpu_program_has_none(self):
+        text = jax.jit(lambda x: x * 2).lower(jnp.ones(4)).compile().as_text()
+        assert hlo_cost.mosaic_kernels(text) == {}

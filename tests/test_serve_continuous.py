@@ -89,6 +89,18 @@ class TestZeroRecompile:
         sc.serve_continuous(make_demo_requests(sc, names, 8, seed=2))
         assert sc.compiles == warm
 
+    def test_chunk_program_text_is_the_served_program(self):
+        """The text comes from the program serving ran, at the shapes it
+        ran with: asking for it traces nothing new."""
+        _, sc, names = _twin_servers()
+        sc.serve_continuous(make_demo_requests(sc, names, 8, seed=1))
+        warm = sc.compiles
+        for backend in ("jnp", "event"):
+            assert "HloModule" in sc.chunk_program_text(backend)
+        assert sc.compiles == warm
+        with pytest.raises(KeyError):
+            sc.chunk_program_text("pallas_fused")
+
 
 class TestAdmissionEdges:
     def test_zero_tick_budget_completes_without_running(self):

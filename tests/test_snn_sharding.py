@@ -333,6 +333,12 @@ class TestShardedChunks:
         # must be identical on every chunk or the second call retraces.
         carry = TickCarry(state=SNNState.zeros((), n),
                           telem=TickTelemetry.zeros(()))
+        # ... and committed to the mesh as the chunk's own output is: an
+        # array's mesh is part of its abstract type, so an uncommitted
+        # seed would trace once more on the first hand-off.
+        carry = snn_sharding.place(
+            carry, snn_sharding.carry_specs(snn_sharding.snn_rules(mesh),
+                                            carry), mesh)
         rasters = []
         for k in range(K):
             carry, ras = chunk_fn(params, carry, ext[k * T:(k + 1) * T])
