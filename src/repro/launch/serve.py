@@ -54,7 +54,8 @@ class ServeRequest:
     is the *enqueue* time: callers that queue requests (the async
     front-end) stamp it at admission so TTFT includes queue wait; the
     servers only stamp it (lazily, when still ``0.0``) for requests
-    handed to them directly.
+    handed to them directly. ``t_admit`` is when continuous admission
+    put the request in a slot (queue wait = ``t_admit - t_submit``).
 
     Result fields (``out``/``counts``/``pred``/timestamps) are filled in
     place as the request completes -- :meth:`ServeResult.of` snapshots
@@ -75,6 +76,7 @@ class ServeRequest:
     counts: Optional[np.ndarray] = None   # (n_out,) rate-decoded counts
     pred: Optional[int] = None            # argmax over output neurons
     t_submit: float = 0.0
+    t_admit: Optional[float] = None
     t_first: Optional[float] = None
     t_done: Optional[float] = None
 
@@ -453,9 +455,9 @@ class SNNServer:
         self._h_wave = r.histogram(
             "snn_wave_seconds", "wave wall time, by resident program",
             ("backend",))
-        self._h_chunk = r.histogram(
-            "snn_chunk_seconds", "chunk wall time, by resident program",
-            ("backend",))
+        self._h_queue_wait = r.histogram(
+            "snn_queue_wait_seconds",
+            "enqueue-to-slot-fill wait under continuous admission")
 
     @property
     def compiles(self) -> int:
@@ -1037,21 +1039,23 @@ class SNNServer:
                 # have arrived between the last chunk and now.
                 n_before = len(rejected)
                 got = False
-                while feeder is not None:
-                    r = feeder()
-                    if r is None:
-                        break
-                    self._route(r, pending_map, rejected)
-                    got = True
+                with span("snn/admit"):
+                    while feeder is not None:
+                        r = feeder()
+                        if r is None:
+                            break
+                        self._route(r, pending_map, rejected)
+                        got = True
                 if not got and len(rejected) == n_before:
                     break
                 continue
             # FIFO across backends: run the program whose queue holds
             # the oldest waiting request.
             backend = min(live, key=lambda b: pending_map[b][0].t_submit)
-            chunks += self._continuous_group(
-                backend, pending_map, rejected, chunk, feeder, on_complete,
-                done)
+            with span(f"snn/group/{backend}"):
+                chunks += self._continuous_group(
+                    backend, pending_map, rejected, chunk, feeder,
+                    on_complete, done)
         self._g_queue.set(0)
         self._g_busy.set(0)
         if not done:
@@ -1096,74 +1100,82 @@ class SNNServer:
         def fill(i: int, r: ServeRequest) -> None:
             nonlocal params_s, carry_s, plastic_c_s, counts_acc
             nonlocal fan_idx_s, fan_mask_s
-            t = self.tenants[r.tenant]
-            slot_req[i], slot_tenant[i] = r, t
-            offset[i] = 0
-            budget[i] = min(int(r.n_ticks), self.max_ticks)
-            if t.plastic:
-                busy_plastic.add(t.name)
-            fresh = self._fresh_slot_carry(t)
-            if params_s is None:
-                # First fill seeds EVERY slot with this tenant's image;
-                # idle slots ride along at budget 0 (masked to nothing),
-                # exactly like the wave path's dummy padding.
-                bcast = lambda x: jax.tree.map(
-                    lambda a: jnp.broadcast_to(a, (S,) + a.shape), x)
-                params_s = bcast(t.params)
-                carry_s = bcast(fresh)
-                counts_acc = jnp.zeros((S, N), jnp.float32)
-                plastic_c_s = jnp.broadcast_to(
-                    t.plastic_c, (S,) + t.plastic_c.shape)
-                if backend == "event":
-                    fan_idx_s = jnp.broadcast_to(
-                        t.fan_idx, (S,) + t.fan_idx.shape)
-                    fan_mask_s = jnp.broadcast_to(
-                        t.fan_mask, (S,) + t.fan_mask.shape)
-                return
-            ev = backend == "event"
-            image = (t.params, fresh, t.plastic_c, zero_row,
-                     t.fan_idx if ev else None, t.fan_mask if ev else None)
-            stacked = (params_s, carry_s, plastic_c_s, counts_acc,
-                       fan_idx_s, fan_mask_s)
-            (params_s, carry_s, plastic_c_s, counts_acc,
-             fan_idx_s, fan_mask_s) = fill_run(stacked, image, i)
+            with span(f"snn/fill/{backend}", rid=r.rid, slot=i):
+                t = self.tenants[r.tenant]
+                r.t_admit = time.time()
+                self._h_queue_wait.observe(max(0.0, r.t_admit - r.t_submit))
+                slot_req[i], slot_tenant[i] = r, t
+                offset[i] = 0
+                budget[i] = min(int(r.n_ticks), self.max_ticks)
+                if t.plastic:
+                    busy_plastic.add(t.name)
+                fresh = self._fresh_slot_carry(t)
+                if params_s is None:
+                    # First fill seeds EVERY slot with this tenant's image;
+                    # idle slots ride along at budget 0 (masked to nothing),
+                    # exactly like the wave path's dummy padding.
+                    bcast = lambda x: jax.tree.map(
+                        lambda a: jnp.broadcast_to(a, (S,) + a.shape), x)
+                    params_s = bcast(t.params)
+                    carry_s = bcast(fresh)
+                    counts_acc = jnp.zeros((S, N), jnp.float32)
+                    plastic_c_s = jnp.broadcast_to(
+                        t.plastic_c, (S,) + t.plastic_c.shape)
+                    if backend == "event":
+                        fan_idx_s = jnp.broadcast_to(
+                            t.fan_idx, (S,) + t.fan_idx.shape)
+                        fan_mask_s = jnp.broadcast_to(
+                            t.fan_mask, (S,) + t.fan_mask.shape)
+                    return
+                ev = backend == "event"
+                image = (t.params, fresh, t.plastic_c, zero_row,
+                         t.fan_idx if ev else None, t.fan_mask if ev else None)
+                stacked = (params_s, carry_s, plastic_c_s, counts_acc,
+                           fan_idx_s, fan_mask_s)
+                (params_s, carry_s, plastic_c_s, counts_acc,
+                 fan_idx_s, fan_mask_s) = fill_run(stacked, image, i)
 
         def retire(i: int, now: float, row: Optional[np.ndarray] = None,
                    tel=None) -> None:
             r, t = slot_req[i], slot_tenant[i]
-            if row is None:   # the retire-time sync point
-                row = np.asarray(counts_acc[i])
-            out = row[t.n - t.n_out: t.n]
-            r.counts = out
-            r.pred = int(out.argmax())
-            r.t_first = r.t_done = now
-            if self.telemetry and carry_s is not None and offset[i] > 0:
-                if tel is None:
-                    tel = jax.tree.map(np.asarray, carry_s.telem)
-                self._observe_slot(t, tel, i)
-                self._c_overflow.inc(float(tel.overflow[i]))
-                self._c_policy.inc(float(tel.policy_dense[i]))
-                self._c_dw.inc(float(tel.dw_l1[i]))
-            if t.plastic:
-                # Register write-back, same as the wave path: the
-                # tenant's next request starts from what this one learned.
-                t.params = dataclasses.replace(t.params, w=carry_s.w[i])
-                busy_plastic.discard(t.name)
-            slot_req[i] = slot_tenant[i] = None
-            done.append(r)
-            self._c_requests.inc()
-            self._c_useful_ticks.inc(int(budget[i]))
-            self._h_ttft.observe(r.t_done - r.t_submit)
-            if on_complete is not None:
-                on_complete(r)
+            with span("snn/retire", rid=r.rid, slot=i):
+                if row is None:   # the retire-time sync point
+                    with span("snn/readback"):
+                        row = np.asarray(counts_acc[i])
+                out = row[t.n - t.n_out: t.n]
+                r.counts = out
+                r.pred = int(out.argmax())
+                r.t_first = r.t_done = now
+                if self.telemetry and carry_s is not None and offset[i] > 0:
+                    if tel is None:
+                        with span("snn/telemetry"):
+                            tel = jax.tree.map(np.asarray, carry_s.telem)
+                    self._observe_slot(t, tel, i)
+                    self._c_overflow.inc(float(tel.overflow[i]))
+                    self._c_policy.inc(float(tel.policy_dense[i]))
+                    self._c_dw.inc(float(tel.dw_l1[i]))
+                if t.plastic:
+                    # Register write-back, same as the wave path: the
+                    # tenant's next request starts from what this one learned.
+                    t.params = dataclasses.replace(t.params, w=carry_s.w[i])
+                    busy_plastic.discard(t.name)
+                slot_req[i] = slot_tenant[i] = None
+                done.append(r)
+                self._c_requests.inc()
+                self._c_useful_ticks.inc(int(budget[i]))
+                self._h_ttft.observe(r.t_done - r.t_submit)
+                if on_complete is not None:
+                    on_complete(r)
 
         while True:
             # Stream in late arrivals (the async front-end's feeder).
-            while feeder is not None:
-                r = feeder()
-                if r is None:
-                    break
-                self._route(r, pending_map, rejected)
+            if feeder is not None:
+                with span("snn/admit"):
+                    while True:
+                        r = feeder()
+                        if r is None:
+                            break
+                        self._route(r, pending_map, rejected)
             # Refill free slots FIFO; zero-budget requests complete
             # without running a tick (counts all-zero, nothing learned).
             for i in range(S):
@@ -1181,33 +1193,33 @@ class SNNServer:
                 if pending:
                     continue   # freed a plastic tenant; re-admit
                 break
-            ext = np.zeros((S, chunk, N), np.float32)
-            rew = np.zeros((S, chunk), np.float32)
-            for i in busy:
-                r = slot_req[i]
-                o = int(offset[i])
-                if r.ext is not None and o < r.ext.shape[0]:
-                    seg = np.asarray(r.ext[o:o + chunk], np.float32)
-                    ext[i, :seg.shape[0], :seg.shape[1]] = seg
-                if r.rewards is not None and o < len(r.rewards):
-                    seg = np.asarray(r.rewards[o:o + chunk], np.float32)
-                    rew[i, :seg.shape[0]] = seg
-            args = (params_s, carry_s, jnp.asarray(ext), plastic_c_s,
-                    jnp.asarray(rew), jnp.asarray(offset, jnp.int32),
-                    jnp.asarray(budget), counts_acc)
-            if backend == "event":
-                args += (fan_idx_s, fan_mask_s)
-            if (backend, chunk) not in self._chunk_arg_specs:
-                self._chunk_arg_specs[(backend, chunk)] = jax.tree.map(
-                    lambda a: jax.ShapeDtypeStruct(
-                        a.shape, a.dtype, sharding=a.sharding,
-                        weak_type=a.weak_type), args)
-            # Dispatch-side timing: counts stay on device, so this span
-            # does NOT wait for the chunk to execute -- consecutive
-            # chunks pipeline, and the device queue only drains at a
-            # retire (the counts row read).
-            with span(f"snn/chunk/{backend}", histogram=self._h_chunk,
-                      backend=backend):
+            with span("snn/assemble"):
+                ext = np.zeros((S, chunk, N), np.float32)
+                rew = np.zeros((S, chunk), np.float32)
+                for i in busy:
+                    r = slot_req[i]
+                    o = int(offset[i])
+                    if r.ext is not None and o < r.ext.shape[0]:
+                        seg = np.asarray(r.ext[o:o + chunk], np.float32)
+                        ext[i, :seg.shape[0], :seg.shape[1]] = seg
+                    if r.rewards is not None and o < len(r.rewards):
+                        seg = np.asarray(r.rewards[o:o + chunk], np.float32)
+                        rew[i, :seg.shape[0]] = seg
+                args = (params_s, carry_s, jnp.asarray(ext), plastic_c_s,
+                        jnp.asarray(rew), jnp.asarray(offset, jnp.int32),
+                        jnp.asarray(budget), counts_acc)
+                if backend == "event":
+                    args += (fan_idx_s, fan_mask_s)
+                if (backend, chunk) not in self._chunk_arg_specs:
+                    self._chunk_arg_specs[(backend, chunk)] = jax.tree.map(
+                        lambda a: jax.ShapeDtypeStruct(
+                            a.shape, a.dtype, sharding=a.sharding,
+                            weak_type=a.weak_type), args)
+            # The dispatch only: counts stay on device, so this span does
+            # NOT wait for the chunk to execute -- consecutive chunks
+            # pipeline, and the device queue only drains at a retire
+            # round's ``snn/readback``.
+            with span(f"snn/chunk/{backend}"):
                 carry_s, counts_acc = run(*args)
             chunks += 1
             self._c_chunks.inc(backend=backend)
@@ -1218,9 +1230,12 @@ class SNNServer:
             if due:
                 # One (S, N) read-back (and one telemetry pull) serves
                 # every retire this round.
-                rows = np.asarray(counts_acc)
-                tel = (jax.tree.map(np.asarray, carry_s.telem)
-                       if self.telemetry else None)
+                with span("snn/readback"):
+                    rows = np.asarray(counts_acc)
+                tel = None
+                if self.telemetry:
+                    with span("snn/telemetry"):
+                        tel = jax.tree.map(np.asarray, carry_s.telem)
                 now = time.time()
                 for i in due:
                     retire(i, now, rows[i], tel)
