@@ -252,6 +252,7 @@ def _serve_chunk_program() -> Program:
             jnp.zeros((S, chunk), jnp.float32),
             jnp.zeros((S,), jnp.int32),
             jnp.zeros((S,), jnp.int32),
+            jnp.zeros((S,), jnp.bool_),
             jnp.zeros((S, N), jnp.float32),
             None, None)
     fn = functools.partial(server._chunk_fn, "jnp", chunk)

@@ -640,14 +640,8 @@ class TickEngine(EngineOptions):
         self, carry, st, state2, w, reward, params, plastic_c, learn_until,
         overflow_inc=None, policy=None, policy_inc=None, s_pre=None,
     ) -> Tuple[TickCarry, jax.Array]:
-        """Shared tick tail: optionally run the plasticity datapath, fold
-        telemetry, and rebuild the carry.
-
-        ``s_pre`` is what arrived (previous emissions), ``s_post`` what was
-        just emitted -- the NeuroCoreX shared datapath. The hook always runs
-        *outside* the tick kernel (including for ``backend="pallas_fused"``):
-        learning is its own fused pass over ``(w, elig, traces)``, a disjoint
-        working set from the tick's ``(v, r, delay line)``.
+        """Shared tick tail: fold telemetry, optionally run the plasticity
+        hook (:meth:`plasticity_hook`), and rebuild the carry.
 
         The default presynaptic events are ``st.lif.y`` (the previous
         tick's emissions; exact for ``max_delay == 1``, which learning
@@ -655,46 +649,62 @@ class TickEngine(EngineOptions):
         gathered full-width arriving spikes so plasticity sees the whole
         presynaptic axis against its local postsynaptic columns.
         """
-        learning = carry.w is not None
         lif_state = state2.lif
         telemetry = self.telemetry and carry.telem is not None
         # Hysteresis slot: updated only by the adaptive knee; every other
         # path passes the carried bit (usually None) through unchanged so
         # the carry pytree stays scan-invariant.
         policy2 = policy if policy is not None else carry.policy
-        dw = None
-        if learning and self.plasticity is not None:
-            from repro.plasticity import rules as plasticity_rules
-
-            pb = self.plasticity_backend or self.backend
-            if pb == "pallas_fused":
-                pb = "pallas"  # the plasticity pass has no whole-tick variant
-            elif pb == "event":
-                pb = "jnp"     # STDP outer products are dense; no event pass
-            with jax.named_scope("tick/plasticity"):
-                pst2, w2 = plasticity_rules.plasticity_step(
-                    carry.plast, st.lif.y if s_pre is None else s_pre,
-                    lif_state.y, w,
-                    params.c if plastic_c is None else plastic_c,
-                    self.plasticity, reward, backend=pb)
-            if learn_until is not None:
-                gate = st.tick < learn_until
-                w2 = jnp.where(gate, w2, w)
-                pst2 = jax.tree.map(
-                    lambda new, old: jnp.where(gate, new, old),
-                    pst2, carry.plast)
-            if telemetry:
-                dw = w2 - w  # the committed delta (after learn_until gating)
-            telem2 = carry.telem.accumulate(
-                lif_state, overflow_inc=overflow_inc, policy_inc=policy_inc,
-                dw=dw) if telemetry else carry.telem
-            return TickCarry(state=state2, plast=pst2, w=w2,
-                             telem=telem2, policy=policy2), lif_state.y
         telem2 = carry.telem.accumulate(
             lif_state, overflow_inc=overflow_inc,
             policy_inc=policy_inc) if telemetry else carry.telem
-        return TickCarry(state=state2, plast=carry.plast, w=carry.w,
+        plast2, w2 = carry.plast, carry.w
+        if carry.w is not None and self.plasticity is not None:
+            plast2, w2 = self.plasticity_hook(
+                carry.plast, w, st.lif.y if s_pre is None else s_pre,
+                lif_state.y, params.c if plastic_c is None else plastic_c,
+                reward,
+                gate=None if learn_until is None else st.tick < learn_until)
+            if telemetry:
+                telem2 = telem2.fold_dw(w2 - w)  # the committed delta
+        return TickCarry(state=state2, plast=plast2, w=w2,
                          telem=telem2, policy=policy2), lif_state.y
+
+    def plasticity_hook(
+        self, plast, w, s_pre, s_post, plastic_c, reward, *, gate=None,
+    ):
+        """One learning tick on one carry's weights; returns
+        ``(plast', w')``.
+
+        ``s_pre`` is what arrived (previous emissions), ``s_post`` what was
+        just emitted -- the NeuroCoreX shared datapath. The hook always runs
+        *outside* the tick kernel (including for ``backend="pallas_fused"``):
+        learning is its own fused pass over ``(w, elig, traces)``, a disjoint
+        working set from the tick's ``(v, r, delay line)``.
+
+        ``gate`` (runtime bool, the ``learn_until`` test) commits the
+        update only where it holds; None commits it unconditionally -- for
+        a caller that decides whether to run the hook at all, as the
+        continuous server's chunk program does per slot.  With telemetry
+        on, the caller folds the committed delta ``w' - w`` in
+        (:meth:`~repro.obs.telemetry.TickTelemetry.fold_dw`).
+        """
+        from repro.plasticity import rules as plasticity_rules
+
+        pb = self.plasticity_backend or self.backend
+        if pb == "pallas_fused":
+            pb = "pallas"  # the plasticity pass has no whole-tick variant
+        elif pb == "event":
+            pb = "jnp"     # STDP outer products are dense; no event pass
+        with jax.named_scope("tick/plasticity"):
+            plast2, w2 = plasticity_rules.plasticity_step(
+                plast, s_pre, s_post, w, plastic_c, self.plasticity, reward,
+                backend=pb)
+        if gate is not None:
+            w2 = jnp.where(gate, w2, w)
+            plast2 = jax.tree.map(
+                lambda new, old: jnp.where(gate, new, old), plast2, plast)
+        return plast2, w2
 
     # -- scan driver -------------------------------------------------------
 

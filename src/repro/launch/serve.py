@@ -334,7 +334,9 @@ class SNNServer:
     Every wave runs the *learning* tick body (the engine's plasticity
     hook); frozen tenants pass an all-zero ``plastic_c``, which the STDP
     rule turns into an exact no-op -- one datapath for inference and
-    learning, as NeuroCoreX does in silicon.
+    learning, as NeuroCoreX does in silicon. The continuous chunk program
+    (:meth:`serve_continuous`) keeps that one datapath but runs the hook
+    only on the slot-ticks that learn, bit-identical to the wave path.
     """
 
     def __init__(self, *, n_max: int, slots: int = 8, max_ticks: int = 32,
@@ -434,6 +436,10 @@ class SNNServer:
         self._c_useful_ticks = r.counter(
             "snn_useful_slot_ticks_total",
             "slot-ticks inside a live request's budget (goodput numerator)")
+        self._c_learning_ticks = r.counter(
+            "snn_learning_slot_ticks_total",
+            "slot-ticks on which the continuous chunk program ran the "
+            "plasticity hook (a plastic tenant inside its budget)")
         self._c_overflow = r.counter(
             "snn_event_overflow_ticks_total",
             "event-backend ticks that overflowed k_active to dense fallback")
@@ -600,50 +606,100 @@ class SNNServer:
         return (counts, w2, out[2]) if self.telemetry else (counts, w2)
 
     def _chunk_fn(self, backend, chunk, params, carry, ext, plastic_c,
-                  rewards, offset, budget, counts_acc,
+                  rewards, offset, budget, learns, counts_acc,
                   fan_idx=None, fan_mask=None):
         """The continuous-admission step: run every resident slot for
         ``chunk`` ticks from its carried state.
 
         ``(slot-batched params, slot-batched TickCarry, (S,chunk,N) ext,
         (S,N,N) mask, (S,chunk) rewards, (S,) tick offsets, (S,)
-        budgets, (S,N) running counts[, fan-in lists]) -> (next carry,
-        (S,N) updated running counts)``.
+        budgets, (S,) learns, (S,N) running counts[, fan-in lists]) ->
+        (next carry, (S,N) updated running counts)``.
 
         Counts accumulate *on device* -- the host only reads a slot's
         row back when its request retires, so consecutive chunks
         dispatch without a host round-trip between them.
 
         Everything per-request is *runtime data* -- offsets, budgets,
-        the carry, even which tenant owns a slot (its registers are just
-        array values) -- so one trace serves every refill; only the
-        chunk size and backend are static. ``learn_until=budget`` rides
-        the carry's own tick counter, which persists across chunks, so
-        plasticity stops at exactly the same absolute tick as the wave
-        path and the learned weights come back bit-identical. The count
-        mask compares the absolute tick index (``offset + arange``)
-        against the budget, so partial counts summed across chunks equal
-        the wave path's one-shot masked sum exactly (small integers in
-        f32 -- order-free)."""
+        ``learns`` (the slot's tenant is plastic), the carry, even which
+        tenant owns a slot (its registers are just array values) -- so
+        one trace serves every refill; only the chunk size and backend
+        are static. The count mask compares the absolute tick index
+        (``offset + arange``) against the budget, so partial counts
+        summed across chunks equal the wave path's one-shot masked sum
+        exactly (small integers in f32 -- order-free).
+
+        Each tick runs the tick body vmapped over the slots without the
+        plasticity hook, then the hook slot by slot under a
+        ``lax.cond``: only on slots that learn, and only while the carry's
+        own tick counter (which persists across chunks) is below the
+        budget -- the wave path's ``learn_until=budget``. Under ``vmap``
+        a ``cond`` would lower to a select that runs both arms; out of
+        it, a frozen slot or a tick past the budget costs the device no
+        STDP pass, no ``learn_until`` select and no weight-delta norms.
+        On those ticks the wave path commits ``W`` unchanged and adds
+        exactly 0 to the norms, so counts, learned weights and telemetry
+        stay bit-identical to it."""
+        from repro.core.engine import TickEngine
         from repro.kernels.ops import EventFanIn
 
         key = f"chunk/{backend}"
         self._compiles[key] = self._compiles.get(key, 0) + 1
         engine = self._engines[backend]
+        # The tick body alone: with no plasticity set, the engine leaves
+        # the hook out though the carry holds W.
+        ticker = TickEngine(dataclasses.replace(engine.options,
+                                                plasticity=None))
 
-        def per_slot(p, c, e, pc, rew, until, fi, fm):
+        def slot_tick(p, c, e, fi, fm):
             nbrs = None if fi is None else EventFanIn(idx=fi, mask=fm)
-            c2, raster = engine.chunk(
-                p, c, e, chunk, rewards=rew, plastic_c=pc,
-                learn_until=until, neighbors=nbrs)
-            return c2, raster
+            c2, y = ticker.chunk(p, c, e[None], 1, neighbors=nbrs)
+            return c2, y[0]
 
-        carry2, raster = jax.vmap(per_slot)(
-            params, carry, ext, plastic_c, rewards, budget,
-            fan_idx, fan_mask)
-        t_abs = offset[:, None] + jnp.arange(chunk)[None, :]     # (S, chunk)
-        tmask = (t_abs < budget[:, None]).astype(raster.dtype)
-        counts = (raster * tmask[:, :, None]).sum(axis=1)        # (S, N)
+        def tick(c, xs):
+            e, rew = xs                                     # (S,N), (S,)
+            go = learns & (c.state.tick < budget)
+            y_pre = c.state.lif.y
+            c, y = jax.vmap(slot_tick)(params, c, e, fan_idx, fan_mask)
+
+            def learn(s, lc):
+                # Plain dynamic slices and in-place updates (``.at[s]``
+                # would add a bounds select that rereads the slot's W).
+                plast, w, telem = lc
+                at = lambda a: jax.lax.dynamic_index_in_dim(a, s, 0, False)
+                put = lambda a, v: jax.lax.dynamic_update_index_in_dim(
+                    a, v, s, 0)
+                w_old = at(w)
+                plast2, w2 = engine.plasticity_hook(
+                    jax.tree.map(at, plast), w_old, at(y_pre), at(y),
+                    at(plastic_c), at(rew))
+                if telem is not None:
+                    # The committed delta's norms, in a cond of their own
+                    # (``go[s]`` holds here): a cond's operands are
+                    # materialized, so the sum reads w2 as stored. XLA may
+                    # otherwise fuse a copy of w2's arithmetic into the
+                    # reduction, which rounds apart from the wave path's
+                    # norms; an optimization barrier does not last until
+                    # the CPU backend's fusion.
+                    tel = jax.lax.cond(
+                        go[s], lambda t, a, b: t.fold_dw(a - b),
+                        lambda t, a, b: t,
+                        jax.tree.map(at, telem), w2, w_old)
+                    telem = jax.tree.map(put, telem, tel)
+                return jax.tree.map(put, plast, plast2), put(w, w2), telem
+
+            plast, w, telem = jax.lax.fori_loop(
+                0, go.shape[0],
+                lambda s, lc: jax.lax.cond(go[s], functools.partial(learn, s),
+                                           lambda lc: lc, lc),
+                (c.plast, c.w, c.telem))
+            return dataclasses.replace(c, plast=plast, w=w, telem=telem), y
+
+        carry2, raster = jax.lax.scan(
+            tick, carry, (jnp.swapaxes(ext, 0, 1), rewards.T))
+        t_abs = jnp.arange(chunk)[:, None] + offset[None, :]     # (chunk, S)
+        tmask = (t_abs < budget[None, :]).astype(raster.dtype)
+        counts = (raster * tmask[:, :, None]).sum(axis=0)        # (S, N)
         return carry2, counts_acc + counts
 
     # -- wave assembly (host side) ----------------------------------------
@@ -1095,6 +1151,7 @@ class SNNServer:
         zero_row = jnp.zeros((N,), jnp.float32)   # refill counts reset
         offset = np.zeros((S,), np.int64)   # absolute ticks already run
         budget = np.zeros((S,), np.int32)
+        learns = np.zeros((S,), bool)       # the slot's tenant is plastic
         chunks = 0
 
         def fill(i: int, r: ServeRequest) -> None:
@@ -1107,6 +1164,7 @@ class SNNServer:
                 slot_req[i], slot_tenant[i] = r, t
                 offset[i] = 0
                 budget[i] = min(int(r.n_ticks), self.max_ticks)
+                learns[i] = t.plastic
                 if t.plastic:
                     busy_plastic.add(t.name)
                 fresh = self._fresh_slot_carry(t)
@@ -1207,7 +1265,7 @@ class SNNServer:
                         rew[i, :seg.shape[0]] = seg
                 args = (params_s, carry_s, jnp.asarray(ext), plastic_c_s,
                         jnp.asarray(rew), jnp.asarray(offset, jnp.int32),
-                        jnp.asarray(budget), counts_acc)
+                        jnp.asarray(budget), jnp.asarray(learns), counts_acc)
                 if backend == "event":
                     args += (fan_idx_s, fan_mask_s)
                 if (backend, chunk) not in self._chunk_arg_specs:
@@ -1219,11 +1277,14 @@ class SNNServer:
             # NOT wait for the chunk to execute -- consecutive chunks
             # pipeline, and the device queue only drains at a retire
             # round's ``snn/readback``.
-            with span(f"snn/chunk/{backend}"):
+            learning = [int(min(chunk, budget[i] - offset[i]))
+                        for i in busy if learns[i] and offset[i] < budget[i]]
+            with span(f"snn/chunk/{backend}", learn=len(learning)):
                 carry_s, counts_acc = run(*args)
             chunks += 1
             self._c_chunks.inc(backend=backend)
             self._c_slot_ticks.inc(S * chunk)
+            self._c_learning_ticks.inc(sum(learning))
             for i in busy:
                 offset[i] += chunk
             due = [i for i in busy if offset[i] >= budget[i]]

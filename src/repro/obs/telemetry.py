@@ -87,7 +87,6 @@ class TickTelemetry:
         *,
         overflow_inc: Optional[jax.Array] = None,
         policy_inc: Optional[jax.Array] = None,
-        dw: Optional[jax.Array] = None,
     ) -> "TickTelemetry":
         """Fold one tick's outputs in (pure reductions over the neuron axis).
 
@@ -98,8 +97,9 @@ class TickTelemetry:
           policy_inc: optional batch-shaped i32 increment (event backend:
             1 on ticks the adaptive knee routed to the dense arm for speed
             -- counted separately from ``overflow_inc``).
-          dw: optional weight delta ``w_new - w_old`` from the plasticity
-            hook (any shape; reduced to scalars and broadcast).
+
+        The plasticity hook's weight delta folds in separately, through
+        :meth:`fold_dw`, on the ticks where the hook runs.
         """
         y, v, r = lif_state.y, lif_state.v, lif_state.r
         n = y.shape[-1]
@@ -116,10 +116,6 @@ class TickTelemetry:
             lambda a, b: (a[0] + b[0], a[1] + b[1],
                           jnp.maximum(a[2], b[2]), a[3] + b[3]),
             (y.ndim - 1,))
-        dw_l1, dw_sq = self.dw_l1, self.dw_sq
-        if dw is not None:
-            dw_l1 = dw_l1 + jnp.abs(dw).sum()
-            dw_sq = dw_sq + jnp.square(dw).sum()
         overflow = self.overflow
         if overflow_inc is not None:
             overflow = overflow + overflow_inc
@@ -134,8 +130,16 @@ class TickTelemetry:
             ref_sum=self.ref_sum + s_r / n,
             overflow=overflow,
             policy_dense=policy_dense,
-            dw_l1=dw_l1,
-            dw_sq=dw_sq)
+            dw_l1=self.dw_l1,
+            dw_sq=self.dw_sq)
+
+    def fold_dw(self, dw: jax.Array) -> "TickTelemetry":
+        """Fold one plasticity tick's committed weight delta
+        ``w_new - w_old`` in (any shape; reduced to scalars)."""
+        return dataclasses.replace(
+            self,
+            dw_l1=self.dw_l1 + jnp.abs(dw).sum(),
+            dw_sq=self.dw_sq + jnp.square(dw).sum())
 
     # -- host-side readout -------------------------------------------------
 

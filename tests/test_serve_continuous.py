@@ -6,6 +6,7 @@ admission, bit for bit -- while never retracing across slot refills,
 mixing dense and event tenants, and handling the admission edges
 (zero-tick budgets, unknown tenants, feeder-streamed late arrivals).
 """
+import functools
 from collections import deque
 
 import jax
@@ -71,6 +72,113 @@ class TestWaveOracle:
         stats = sc.serve_continuous(reqs)
         assert stats["requests_served"] == 12
         assert set(stats["backends"]) == {"jnp", "event"}
+
+
+def _requests(server, spec, *, seed):
+    """Requests as ``(tenant, budget)`` pairs, with random impulse drive
+    for all ``max_ticks`` ticks: the fabric keeps spiking past a budget,
+    so a tick past it would learn if the budget did not stop the hook."""
+    rng = np.random.default_rng(seed)
+    reqs = []
+    T = server.max_ticks
+    for i, (name, ticks) in enumerate(spec):
+        t = server.tenants[name]
+        ext = ((rng.random((T, t.n_in)) < 0.3)
+               * rng.integers(80, 255, (T, t.n_in))).astype(np.float32)
+        reqs.append(ServeRequest(rid=i, tenant=name, ext=ext, n_ticks=ticks))
+    return reqs
+
+
+# Which tenants learn, against the chunk program's per-slot learning cond:
+# the demo's one plastic tenant is "dense-7"; "sparse-*" ride the event
+# program (frozen); the rest are frozen dense tenants.
+_LEARNING_CASES = {
+    "plastic_and_frozen_mixed": [
+        ("dense-7", 9), ("layered-0", 12), ("dense-3", 5), ("dense-7", 12),
+        ("ring-1", 7), ("layered-4", 4), ("dense-7", 6), ("ring-5", 10)],
+    "all_frozen": [
+        ("dense-3", 9), ("layered-0", 12), ("ring-1", 5), ("layered-4", 7),
+        ("ring-5", 3)],
+    "plastic_budget_ends_mid_chunk": [
+        ("dense-7", 3), ("dense-3", 8), ("dense-7", 11), ("layered-0", 4),
+        ("dense-7", 3)],
+    "event_frozen_slots": [
+        ("sparse-2", 9), ("sparse-6", 12), ("sparse-2", 5), ("sparse-6", 3),
+        ("sparse-2", 11)],
+}
+
+
+class TestLearningOnlyWhereItLearns:
+    """The chunk program runs the plasticity hook only on slot-ticks that
+    learn; the wave path runs it (gated by ``learn_until``) on every
+    slot-tick, and is the oracle."""
+
+    @pytest.mark.parametrize("case", sorted(_LEARNING_CASES))
+    def test_bit_exact_vs_wave(self, case):
+        sw, sc, _ = _twin_servers()
+        spec = _LEARNING_CASES[case]
+        reqs_w = _requests(sw, spec, seed=7)
+        reqs_c = _requests(sc, spec, seed=7)
+        sw.serve(reqs_w)
+        sc.serve_continuous(reqs_c, chunk_ticks=4)
+        for a, b in zip(reqs_w, reqs_c):
+            assert a.pred == b.pred
+            np.testing.assert_array_equal(a.counts, b.counts)
+        for n in sw.tenants:
+            np.testing.assert_array_equal(
+                np.asarray(sw.tenants[n].params.w),
+                np.asarray(sc.tenants[n].params.w))
+        rep_w, rep_c = sw.tenant_report(), sc.tenant_report()
+        assert set(rep_w) == set(rep_c)
+        for n in rep_w:
+            assert rep_w[n]["dw_l1"] == rep_c[n]["dw_l1"]
+            assert sw._tenant_obs[n]["dw_l1"] == sc._tenant_obs[n]["dw_l1"]
+        plastic_dw = sum(rep_c[n]["dw_l1"] for n in rep_c
+                         if sc.tenants[n].plastic)
+        assert (plastic_dw > 0) == any(n == "dense-7" for n, _ in spec)
+
+    @pytest.mark.parametrize("case", sorted(_LEARNING_CASES))
+    def test_learning_slot_ticks_counted(self, case):
+        _, sc, _ = _twin_servers()
+        spec = _LEARNING_CASES[case]
+        sc.serve_continuous(_requests(sc, spec, seed=7), chunk_ticks=4)
+        want = sum(min(ticks, sc.max_ticks) for name, ticks in spec
+                   if sc.tenants[name].plastic)
+        reg = sc.registry
+        assert reg.get("snn_learning_slot_ticks_total").value() == want
+        assert 0 < reg.get("snn_slot_ticks_total").value()
+        if case in ("all_frozen", "event_frozen_slots"):
+            assert want == 0
+
+    def test_hook_only_inside_a_per_slot_cond(self):
+        """In the chunk program's jaxpr the plasticity step sits only in a
+        ``cond`` branch, and no select over a stacked ``(S, N, N)`` operand
+        (a vmapped ``cond`` or ``learn_until`` gate) is left outside."""
+        _, sc, names = _twin_servers()
+        sc.serve_continuous(make_demo_requests(sc, names, 8, seed=1))
+        key = ("jnp", sc.chunk_ticks)
+        jaxpr = jax.make_jaxpr(
+            functools.partial(sc._chunk_fn, *key))(*sc._chunk_arg_specs[key])
+        S, N = sc.slots, sc.n_max
+        found = []
+
+        def walk(jx, in_cond):
+            for eqn in jx.eqns:
+                found.append((eqn, in_cond))
+                for v in eqn.params.values():
+                    for sub in v if isinstance(v, (tuple, list)) else (v,):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            walk(sub, in_cond or eqn.primitive.name == "cond")
+
+        walk(jaxpr.jaxpr, False)
+        hook = [c for e, c in found
+                if "tick/plasticity" in str(e.source_info.name_stack)]
+        assert hook and all(hook)
+        stacked = [e for e, c in found if not c and e.primitive.name ==
+                   "select_n" and any(getattr(v.aval, "shape", ()) ==
+                                      (S, N, N) for v in e.invars)]
+        assert not stacked
 
 
 class TestZeroRecompile:

@@ -174,9 +174,10 @@ class TestKernels:
 
 
 def test_server_chunk_vmapped_over_slots(one_chip, monkeypatch):
-    """The continuous-serving chunk program of a ``pallas_fused`` server,
-    vmapped over 8 slots exactly as :class:`SNNServer` builds it: the
-    whole-tick kernel and the STDP kernel both survive the slot vmap."""
+    """The continuous-serving chunk program of a ``pallas_fused`` server
+    over 8 slots, exactly as :class:`SNNServer` builds it: the whole-tick
+    kernel survives the slot vmap, and the STDP kernel has one call site,
+    in the per-slot learning ``cond`` the slot loop runs."""
     from repro.launch.serve import SNNServer
 
     # The kernel bridges pick interpret mode off-TPU; this process only
@@ -195,7 +196,7 @@ def test_server_chunk_vmapped_over_slots(one_chip, monkeypatch):
     fn = functools.partial(server._chunk_fn, "pallas_fused", chunk)
     c = _compile(fn, _spec(params, one_chip, lead), _spec(carry, one_chip, lead),
                  arr((chunk, n)), arr((n, n)), arr((chunk,)), arr((), I32),
-                 arr((), I32), arr((n,)))
+                 arr((), I32), arr((), jnp.bool_), arr((n,)))
     # The whole-tick kernel and the STDP pass: both Mosaic kernels.
     assert mosaic_kernels(c.as_text()) == {"fused_tick": 1,
                                            "fused_stdp_step": 1}
