@@ -10,7 +10,10 @@ lint).  :func:`iter_programs` yields the full shipped matrix:
 
 * tick programs -- 4 backends x frozen/learning x telemetry on/off
   (16 programs), the event knee variant riding on the frozen event
-  programs so the adaptive ``lax.cond`` arms are linted as shipped;
+  programs so the adaptive ``lax.cond`` arms are linted as shipped,
+  and the event backend's ``fan_out`` chunk (``psc_exp`` neurons,
+  per-synapse delays into the ring, on-device Poisson drive) with
+  telemetry on and off;
 * serve programs -- the wave program (dense + event), the continuous
   chunked step, and the slot-refill register-download program;
 * kernel launches -- each Pallas kernel's descriptor at a
@@ -184,6 +187,52 @@ def _tick_sharded_program(learning: bool, telemetry: bool) -> Program:
         options_factory=functools.partial(
             _sharded_options, learning, telemetry),
     )
+
+
+def _fan_out_options(telemetry: bool):
+    from repro.core.engine import EngineOptions
+
+    return EngineOptions(mode="psc_exp", backend="event",
+                         event_dispatch="fan_out", event_k_active=4,
+                         telemetry=telemetry)
+
+
+def _fan_out_program(telemetry: bool) -> Program:
+    """The microcircuit's chunk program in miniature: a resident
+    fan-out with per-synapse weights and delays, psc_exp neurons and the
+    Poisson drive (``configs/pd_microcircuit.py`` at full size)."""
+    import jax
+
+    from repro.core import connectivity
+    from repro.core.engine import TickCarry, TickEngine
+    from repro.core.lif import LIFParams
+    from repro.core.network_types import PoissonDrive, SNNParams, SNNState
+
+    n, depth = _N, 4
+    rng = np.random.default_rng(4)
+    src, tgt = np.nonzero(connectivity.sparse_random(n, 0.3, seed=4))
+    fo = connectivity.fan_out_from_synapses(
+        src, tgt, rng.integers(-64, 64, src.size) * 2.0 ** -4,
+        rng.integers(1, depth + 1, src.size), (0, n // 2, n))
+    params = SNNParams(
+        w=None, c=None, w_in=jnp.zeros((0, n), jnp.float32),
+        lif=LIFParams.psc_exp(n, c_m=250.0, tau_m=10.0, tau_syn=0.5,
+                              t_ref=2.0, e_l=-65.0, v_th=-50.0,
+                              v_reset=-65.0, dt=0.1),
+        drive=PoissonDrive(key=jax.random.PRNGKey(0),
+                           lam=jnp.full((n,), 1.5, jnp.float32),
+                           weight=jnp.full((n,), 87.8125, jnp.float32)))
+    engine = TickEngine(_fan_out_options(telemetry))
+    carry = TickCarry(state=SNNState.zeros((), n, depth, current=True))
+
+    def fn(params, carry, fo):
+        return engine.chunk(params, carry, None, _TICKS, neighbors=fo)
+
+    tel = "telem" if telemetry else "notelem"
+    return Program(
+        name=f"tick/fan_out/psc_exp/{tel}", fn=fn, args=(params, carry, fo),
+        n=n, hoist=jaxpr_rules.HOIST_SKIP,
+        options_factory=functools.partial(_fan_out_options, telemetry))
 
 
 # ---------------------------------------------------------------------------
@@ -370,6 +419,7 @@ def program_names() -> Tuple[str, ...]:
              for t in ("frozen", "learning")
              for tel in ("notelem", "telem")]
     names += ["tick/sharded/frozen/notelem", "tick/sharded/learning/telem"]
+    names += ["tick/fan_out/psc_exp/notelem", "tick/fan_out/psc_exp/telem"]
     names += ["serve/wave/jnp", "serve/wave/event", "serve/chunk/jnp",
               "serve/refill/jnp"]
     names += [f"kernel/{reg}" for reg, _ in kernel_launches()]
@@ -380,6 +430,8 @@ def build_program(name: str) -> Program:
     """Build one program by name (lazy -- nothing traces until a rule
     asks for the jaxpr)."""
     parts = name.split("/")
+    if name.startswith("tick/fan_out/psc_exp/"):
+        return _fan_out_program(parts[-1] == "telem")
     if parts[0] == "tick":
         _, backend, tag, tel = parts
         if backend == "sharded":

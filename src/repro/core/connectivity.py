@@ -23,8 +23,10 @@ the cap costs).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence, Tuple
+import functools
+from typing import Iterable, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -351,3 +353,187 @@ def stats(c: np.ndarray) -> ConnectivityStats:
         max_fan_in=max_fi, mean_fan_in=float(fi.mean()) if n else 0.0,
         max_fan_out=max_fo, mean_fan_out=float(fo.mean()) if n else 0.0,
         padding_fraction_in=frac(max_fi), padding_fraction_out=frac(max_fo))
+
+
+# ---------------------------------------------------------------------------
+# Resident fan-out lists with per-synapse weight and delay (the event
+# backend's "fan_out" strategy: a fabric too large for a dense W)
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["targets", "weights", "delays", "offset", "count",
+                 "pop_starts"],
+    meta_fields=[])
+@dataclasses.dataclass(frozen=True)
+class FanOut:
+    """Every synapse of a fabric, stored by source, resident on the device.
+
+    The entries live in rows of ``window`` (the arrays' second axis):
+    source ``s`` owns rows ``offset[s] ..`` -- as many as its
+    population's largest out-degree needs, so each population is padded
+    to its own cap -- and its first ``count[s]`` entries are real.
+    Multapses are just repeated entries.  A spiking source's fan-out is
+    read as ``ceil(count / window)`` whole rows.
+
+    Attributes:
+      targets: ``(rows, window)`` int32 postsynaptic neuron of each entry.
+      weights: ``(rows, window)`` float32 weight of each entry.
+      delays: ``(rows, window)`` uint8 delay of each entry, in ticks >= 1.
+      offset: ``(n,)`` int32 first row of each source.
+      count: ``(n,)`` int32 real entries of each source.
+      pop_starts: ``(n_pops + 1,)`` int32 first neuron of each
+        population, then ``n`` (populations are contiguous ranges).
+    """
+
+    targets: jax.Array
+    weights: jax.Array
+    delays: jax.Array
+    offset: jax.Array
+    count: jax.Array
+    pop_starts: jax.Array
+
+    @property
+    def n(self) -> int:
+        return self.offset.shape[0]
+
+    @property
+    def n_pops(self) -> int:
+        return self.pop_starts.shape[0] - 1
+
+    @property
+    def window(self) -> int:
+        return self.targets.shape[1]
+
+    @property
+    def padded_entries(self) -> int:
+        return self.targets.shape[0] * self.targets.shape[1]
+
+    def stats(self) -> Tuple[int, int, float]:
+        """``(entries, padded_entries, padding_fraction)`` (reads
+        ``count`` back to the host)."""
+        entries = int(np.asarray(self.count, np.int64).sum())
+        padded = self.padded_entries
+        return entries, padded, 1.0 - entries / max(1, padded)
+
+
+def fan_out_layout(count: np.ndarray, pop_starts: Sequence[int],
+                   window: int):
+    """Host-side layout of a :class:`FanOut`: ``(offset, rows)``.
+
+    ``count`` is every source's out-degree and ``pop_starts`` the first
+    neuron of each population plus ``n`` at the end.  Each source of a
+    population gets the rows of ``window`` entries its population's
+    largest out-degree needs (at least one)."""
+    count = np.asarray(count, np.int64)
+    starts = list(pop_starts)
+    n = count.shape[0]
+    if starts[0] != 0 or starts[-1] != n or np.any(np.diff(starts) <= 0):
+        raise ValueError(f"pop_starts must rise from 0 to n={n}, got {starts}")
+    offset = np.zeros(n, np.int64)
+    rows = 0
+    for a, b in zip(starts, starts[1:]):
+        per = max(1, -(-int(count[a:b].max()) // window))
+        offset[a:b] = rows + per * np.arange(b - a)
+        rows += per * (b - a)
+    if (rows + 1) * window >= 2 ** 31:
+        raise ValueError(f"{rows} rows of {window} overflow int32 positions")
+    return offset.astype(np.int32), rows
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _insert(flat, fill, first_pos, src, tgt, w, d):
+    """Place one block of synapses into the flat (row-major) entry arrays:
+    each lands after the entries its source already holds (``fill``);
+    padding rows carry ``src == n``.  A block already grouped by source
+    (ascending, as a generator of the list writes it) skips the sort."""
+    n, size = first_pos.shape[0], flat[0].shape[0]
+    key = jnp.where((src >= 0) & (src < n), src, n)
+
+    def by_source(args):
+        order = jnp.argsort(args[0], stable=True)
+        return tuple(a[order] for a in args)
+
+    key, tgt, w, d = jax.lax.cond(jnp.all(key[1:] >= key[:-1]),
+                                  lambda args: args, by_source,
+                                  (key, tgt, w, d))
+    m = key.shape[0]
+    idx = jnp.arange(m, dtype=jnp.int32)
+    head = jnp.concatenate([jnp.ones((1,), bool), key[1:] != key[:-1]])
+    tail = jnp.concatenate([key[1:] != key[:-1], jnp.ones((1,), bool)])
+    first = jax.lax.cummax(jnp.where(head, idx, 0))
+    ok = key < n
+    s_c = jnp.minimum(key, n - 1)
+    # increasing and distinct: real entries in source order, then the
+    # padding rows past the end (dropped)
+    pos = jnp.where(ok, first_pos[s_c] + fill[s_c] + (idx - first),
+                    size + idx)
+    put = lambda a, v: a.at[pos].set(v.astype(a.dtype), mode="drop",
+                                     indices_are_sorted=True,
+                                     unique_indices=True)
+    flat = (put(flat[0], tgt), put(flat[1], w), put(flat[2], d))
+    fill = fill.at[jnp.where(tail & ok, key, n + idx)].add(
+        idx - first + 1, mode="drop", indices_are_sorted=True,
+        unique_indices=True)
+    return flat, fill
+
+
+def build_fan_out(count, pop_starts: Sequence[int], blocks: Iterable,
+                  window: Optional[int] = None) -> FanOut:
+    """Build a :class:`FanOut` on the device from a synapse list.
+
+    ``count`` (host ints) is each source's out-degree; ``blocks`` yields
+    the list as ``(src, tgt, w, d)`` device arrays of one fixed length,
+    in any order, padding rows with ``src == n`` -- one block is
+    generated (span ``snn/build/synapses``) and placed (span
+    ``snn/build/fanout``) at a time, so set-up holds the fan-out and one
+    block.  Raises unless the blocks held exactly ``count`` synapses per
+    source (a short or long list would leave holes or overwrite rows).
+    ``window`` is the row width (default: the largest out-degree, one
+    row per source).
+    """
+    from repro.obs.tracing import span
+
+    count = np.asarray(count)
+    starts = list(pop_starts)
+    if window is None:
+        window = max(1, int(count.max()))
+    offset, rows = fan_out_layout(count, starts, int(window))
+    size = rows * int(window)
+    flat = (jnp.zeros((size,), jnp.int32), jnp.zeros((size,), jnp.float32),
+            jnp.zeros((size,), jnp.uint8))
+    first_pos = jnp.asarray(offset.astype(np.int64) * window, jnp.int32)
+    fill = jnp.zeros((count.shape[0],), jnp.int32)
+    it = iter(blocks)
+    while True:
+        with span("snn/build/synapses"):
+            blk = next(it, None)
+        if blk is None:
+            break
+        with span("snn/build/fanout"):
+            flat, fill = _insert(flat, fill, first_pos, *blk)
+    if not np.array_equal(np.asarray(fill), count):
+        raise ValueError(
+            "the synapse blocks do not match the out-degrees the layout "
+            "was built for: a fan-out row is short or overfull")
+    with span("snn/build/fanout"):
+        rows_of = jax.jit(lambda a: a.reshape(rows, int(window)),
+                          donate_argnums=0)
+        arrays = [rows_of(a) for a in flat]
+    del flat
+    return FanOut(targets=arrays[0], weights=arrays[1], delays=arrays[2],
+                  offset=jnp.asarray(offset), count=jnp.asarray(count,
+                                                                jnp.int32),
+                  pop_starts=jnp.asarray(starts, jnp.int32))
+
+
+def fan_out_from_synapses(src, tgt, w, d, pop_starts: Sequence[int],
+                          window: Optional[int] = None) -> FanOut:
+    """:func:`build_fan_out` of one host-side synapse list (small
+    fabrics and tests)."""
+    src = np.asarray(src)
+    n = int(pop_starts[-1])
+    count = np.bincount(src, minlength=n)
+    return build_fan_out(count, pop_starts, [tuple(
+        jnp.asarray(a) for a in (src, tgt, w, d))], window)

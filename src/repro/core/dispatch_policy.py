@@ -209,8 +209,9 @@ def is_diagonal(w_in: Optional[np.ndarray]) -> bool:
 
 
 def plan(
-    c,
+    c=None,
     *,
+    fan_out=None,
     w_in=None,
     batch: int = 1,
     rate: Optional[float] = None,
@@ -229,7 +230,13 @@ def plan(
     *plan* does, once, at admission/build time).
 
     Args:
-      c: concrete ``(n, n)`` connectivity (bool/0-1).
+      c: concrete ``(n, n)`` connectivity (bool/0-1); may be None when
+        ``fan_out`` is given.
+      fan_out: a :class:`~repro.core.connectivity.FanOut` -- per-synapse
+        weights and delays resident by source.  Given, the plan is the
+        "fan_out" strategy (the one formulation that holds per-synapse
+        delays without a dense matrix) with ``k_active`` as its block
+        of row reads; it is never picked otherwise.
       w_in: concrete input matrix; diagonal ``w_in`` enables the
         elementwise drive (see module docstring).
       batch: batch size the rollout will run at (cost-model input).
@@ -258,6 +265,19 @@ def plan(
             "bench setup -- and pass the resulting DispatchPlan in")
     from repro.core import connectivity
 
+    if fan_out is not None:
+        n = fan_out.n
+        if rate is not None and k_active is None:
+            k_active = max(8, int(2 * rate * n))
+        k = int(k_active) if k_active is not None else resolve_k_active(n)
+        return DispatchPlan(
+            strategy="fan_out", k_active=k, knee=None,
+            hysteresis=DEFAULT_HYSTERESIS, neighbors=fan_out,
+            ext_diag=is_diagonal(w_in), cap=fan_out.window,
+            costs={"fan_out": float(batch) * k * fan_out.window
+                   * gather_penalty(platform)})
+    if c is None:
+        raise ValueError("plan needs connectivity c, or fan-out lists")
     c_np = np.asarray(c) > 0
     n = c_np.shape[0]
     st = connectivity.stats(c_np)

@@ -62,9 +62,9 @@ from repro.deprecation import warn_deprecated
 from repro.core.network_types import SNNParams, SNNState  # noqa: F401 (re-export surface)
 
 _BACKENDS = ("jnp", "pallas", "pallas_fused", "event")
-_MODES = ("fixed_leak", "euler", "int")
+_MODES = ("fixed_leak", "euler", "int", "psc_exp")
 _OVERFLOW = ("fallback", "strict", "unchecked")
-_DISPATCH = ("auto", "fan_in", "topk", "dense")
+_DISPATCH = ("auto", "fan_in", "topk", "dense", "fan_out")
 
 
 @jax.tree_util.register_dataclass
@@ -117,7 +117,9 @@ class EngineOptions:
     those accept remain as a deprecation shim for one release.
 
     Attributes:
-      mode: LIF formulation ("fixed_leak" | "euler" | "int").
+      mode: LIF formulation ("fixed_leak" | "euler" | "int" | "psc_exp";
+        "psc_exp", NEST's current-based ``iaf_psc_exp``, runs on the jnp
+        and event backends only, and not on the event top-k kernel).
       surrogate: differentiable surrogate spike (training; jnp/event only).
       backend: "jnp" (reference), "pallas" (fused matmul+LIF kernel),
         "pallas_fused" (whole-tick megakernel, one launch per tick) or
@@ -132,7 +134,9 @@ class EngineOptions:
         dispatch (None -> ``n // 8``, floored at 8, via
         :func:`repro.core.dispatch_policy.resolve_k_active`); rows
         spiking past it fall back to the dense product per
-        ``event_overflow``.
+        ``event_overflow``.  For "fan_out" it is the block of row reads
+        (``window`` fan-out entries each): a tick with more reads runs
+        further blocks (never drops a spike).
       event_overflow: "fallback" (dense product on overflow ticks,
         exact at any rate), "strict" (checkify error) or "unchecked".
       event_dispatch: the event backend's synaptic-input formulation --
@@ -142,7 +146,11 @@ class EngineOptions:
         (spike-list gather) or "dense" (masked product; still the event
         backend: it keeps the diagonal-drive elimination and telemetry,
         it just computes the synaptic product densely because the
-        topology is past the gather knee on this platform).
+        topology is past the gather knee on this platform) or "fan_out"
+        (requires a :class:`~repro.core.connectivity.FanOut` as
+        ``neighbors``: spiking sources push their per-synapse weights
+        into the state's delay ring at their per-synapse delays, see
+        :meth:`TickEngine._fan_out_tick`; unbatched, unsharded, frozen).
       event_knee: per-tick adaptive switch for the "topk" strategy:
         ticks whose max batch-row spike count exceeds this run the
         dense product instead of the spike-list gather (both arms
@@ -202,6 +210,14 @@ class EngineOptions:
         if self.mode not in _MODES:
             raise ValueError(
                 f"mode must be one of {_MODES}, got {self.mode!r}")
+        if self.mode == "psc_exp" and (
+                self.backend not in ("jnp", "event")
+                or (self.backend == "event" and self.event_dispatch == "topk")):
+            raise ValueError(
+                "mode='psc_exp' runs on the jnp and event backends only "
+                "(and not on the event top-k kernel): the Pallas kernels "
+                f"carry no synaptic current, got backend={self.backend!r}, "
+                f"event_dispatch={self.event_dispatch!r}")
         if self.plasticity_backend not in (None,) + _BACKENDS:
             raise ValueError(
                 f"plasticity_backend must be None or one of {_BACKENDS}, "
@@ -243,6 +259,10 @@ class EngineOptions:
             if axis not in names:
                 raise ValueError(
                     f"shard_axis {axis!r} is not a mesh axis (axes: {names})")
+        if self.sharded and self.event_dispatch == "fan_out":
+            raise ValueError(
+                "event_dispatch='fan_out' is single-device: its delay ring "
+                "and fan-out lists are not sharded")
         if self.sharded and self.event_ext_diag:
             raise ValueError(
                 "event_ext_diag is unavailable on the sharded path: each "
@@ -286,15 +306,37 @@ class EngineOptions:
             return "pallas"
         return self.backend
 
+    def _fan_out(self, neighbors: Optional[Any]) -> bool:
+        """Whether the event backend runs its ``fan_out`` strategy (the
+        pairing of option and lists checked by :meth:`_event_strategy`)."""
+        from repro.core.connectivity import FanOut
+
+        if self.event_dispatch != "fan_out" and not isinstance(
+                neighbors, FanOut):
+            return False
+        return self._event_strategy(neighbors) == "fan_out"
+
     def _event_strategy(self, neighbors: Optional[Any]) -> str:
         """Resolve ``event_dispatch`` against what the call provided."""
+        from repro.core.connectivity import FanOut
+
         strategy = self.event_dispatch
+        fan_out = isinstance(neighbors, FanOut)
         if strategy == "auto":
-            strategy = "fan_in" if neighbors is not None else "topk"
-        if strategy not in ("fan_in", "topk", "dense"):
+            strategy = ("fan_out" if fan_out else
+                        "fan_in" if neighbors is not None else "topk")
+        if strategy not in ("fan_in", "topk", "dense", "fan_out"):
             raise ValueError(
-                f"event_dispatch must be auto|fan_in|topk|dense, got "
+                f"event_dispatch must be auto|fan_in|topk|dense|fan_out, got "
                 f"{self.event_dispatch!r}")
+        if (strategy == "fan_out") != fan_out:
+            raise ValueError(
+                "event_dispatch='fan_out' takes (and only it takes) "
+                "fan-out lists: pass neighbors=connectivity.FanOut")
+        if self.mode == "psc_exp" and strategy == "topk":
+            raise ValueError(
+                "mode='psc_exp' has no event top-k kernel: pass fan-out or "
+                "fan-in lists, or event_dispatch='dense'")
         if strategy == "fan_in" and neighbors is None:
             raise ValueError(
                 "event_dispatch='fan_in' needs fan-in neighbor lists: pass "
@@ -423,6 +465,13 @@ class TickEngine(EngineOptions):
                 "operand and mask per tile")
 
         max_delay = st.delay_buf.shape[-2]
+
+        if backend == "event" and self._fan_out(neighbors):
+            return self._fan_out_tick(carry, ext, reward, params, neighbors)
+        if params.drive is not None:
+            raise ValueError(
+                "the Poisson drive (SNNParams.drive) runs on the event "
+                "backend's fan_out strategy only")
 
         if backend == "pallas_fused":
             # -- whole-tick megakernel: delay read, masked accumulation, LIF
@@ -636,9 +685,73 @@ class TickEngine(EngineOptions):
                                policy=policy_out, policy_inc=policy_inc,
                                s_pre=s_pre)
 
+    def _fan_out_tick(
+        self, carry: TickCarry, ext, reward, params: SNNParams, fan_out,
+    ) -> Tuple[TickCarry, jax.Array]:
+        """One tick of the event backend's ``fan_out`` strategy.
+
+        The state's ``delay_buf`` is the postsynaptic ring (see
+        :class:`~repro.core.network_types.SNNState`)::
+
+            x      = ring[t % D] (+ the Poisson drive)
+            ring[t % D] = 0
+            LIF(x)  ->  spikes
+            ring[(t + d) % D, target] += w    for each spiking source's
+                                              fan-out entry (target, w, d)
+
+        The cost scales with spikes x fan-out, not with ``D x n^2``.
+        Spiking sources' rows are read ``fan_out.window`` entries at a
+        time, in blocks of ``event_k_active`` reads; a tick with more
+        reads runs further blocks, counted as ``spill_blocks`` in the
+        telemetry.  With weights on a dyadic grid every ring sum
+        is exact, in any order.
+        """
+        from repro.core import dispatch_policy
+
+        st = carry.state
+        if carry.w is not None:
+            raise ValueError("event_dispatch='fan_out' is frozen-weight "
+                             "only (no plasticity on the fan-out lists)")
+        if st.lif.v.ndim != 1:
+            raise ValueError(
+                "event_dispatch='fan_out' runs one unbatched fabric; vmap "
+                f"it for a batch (state shape {st.lif.v.shape})")
+        if ext is not None:
+            raise ValueError(
+                "event_dispatch='fan_out' takes no external input: its "
+                "drive is SNNParams.drive, drawn on the device")
+        ring = st.delay_buf
+        slot = jnp.mod(st.tick, ring.shape[-2])
+        with jax.named_scope("tick/event/fan_out/drive"):
+            syn = jax.lax.dynamic_index_in_dim(ring, slot, axis=-2,
+                                               keepdims=False)
+            ring = jax.lax.dynamic_update_index_in_dim(
+                ring, jnp.zeros_like(syn), slot, axis=-2)
+            if params.drive is not None:
+                syn = syn + params.drive.input(st.tick)
+        lif_state = lif_step(st.lif, syn, params.lif, mode=self.mode,
+                             surrogate=self.surrogate)
+        # a block of reads, not of sources: not capped at n
+        k = (int(self.event_k_active) if self.event_k_active is not None
+             else dispatch_policy.resolve_k_active(fan_out.n))
+        with jax.named_scope("tick/event/fan_out/deliver"):
+            ring, blocks = fan_out_deliver(ring, lif_state.y, st.tick,
+                                           fan_out, k)
+        state2 = SNNState(lif=lif_state, delay_buf=ring, tick=st.tick + 1)
+        inc = None
+        if self.telemetry and carry.telem is not None:
+            fired = lif_state.y > 0
+            inc = (jnp.sum(jnp.where(fired, fan_out.count, 0)).astype(
+                       jnp.float32),
+                   jnp.maximum(blocks - 1, 0),
+                   pop_counts(lif_state.y, fan_out.pop_starts))
+        return self._tick_tail(carry, st, state2, None, reward, params,
+                               None, None, fan_out_inc=inc)
+
     def _tick_tail(
         self, carry, st, state2, w, reward, params, plastic_c, learn_until,
         overflow_inc=None, policy=None, policy_inc=None, s_pre=None,
+        fan_out_inc=None,
     ) -> Tuple[TickCarry, jax.Array]:
         """Shared tick tail: fold telemetry, optionally run the plasticity
         hook (:meth:`plasticity_hook`), and rebuild the carry.
@@ -657,7 +770,8 @@ class TickEngine(EngineOptions):
         policy2 = policy if policy is not None else carry.policy
         telem2 = carry.telem.accumulate(
             lif_state, overflow_inc=overflow_inc,
-            policy_inc=policy_inc) if telemetry else carry.telem
+            policy_inc=policy_inc,
+            fan_out_inc=fan_out_inc) if telemetry else carry.telem
         plast2, w2 = carry.plast, carry.w
         if carry.w is not None and self.plasticity is not None:
             plast2, w2 = self.plasticity_hook(
@@ -716,9 +830,12 @@ class TickEngine(EngineOptions):
         if self.telemetry and carry0.telem is None:
             from repro.obs.telemetry import TickTelemetry
 
+            fan_out = self.backend == "event" and self._fan_out(neighbors)
             carry0 = dataclasses.replace(
                 carry0,
-                telem=TickTelemetry.zeros(carry0.state.lif.v.shape[:-1]))
+                telem=TickTelemetry.zeros(
+                    carry0.state.lif.v.shape[:-1],
+                    n_pops=neighbors.n_pops if fan_out else None))
         if (self.backend == "event" and self.event_knee is not None
                 and carry0.policy is None
                 and self._event_strategy(neighbors) == "topk"):
@@ -923,3 +1040,58 @@ class TickEngine(EngineOptions):
         return self.scan(params, carry, ext_seq, n_ticks,
                          rewards=rewards, plastic_c=plastic_c,
                          learn_until=learn_until, neighbors=neighbors)
+
+
+def pop_counts(y: jax.Array, pop_starts: jax.Array) -> jax.Array:
+    """Spikes of ``y`` per contiguous population."""
+    c = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                         jnp.cumsum((y > 0).astype(jnp.int32))])
+    return (c[pop_starts[1:]] - c[pop_starts[:-1]]).astype(jnp.float32)
+
+
+def read_block(csum: jax.Array, reads: jax.Array, block: jax.Array,
+               k: int):
+    """Block ``block`` of a tick's row reads: reads number ``block * k + 1
+    .. (block + 1) * k`` in source order, as ``(src, part, live)`` --
+    each read's source, which ``window``-wide part of the source's row it
+    is, and which of the ``k`` slots are real.  ``reads`` is each
+    neuron's read count this tick (0 if silent) and ``csum`` its cumsum;
+    each slot is a binary search of ``csum``, so nothing is truncated."""
+    rank = block * k + jnp.arange(1, k + 1, dtype=jnp.int32)
+    live = rank <= csum[-1]
+    src = jnp.where(live, jnp.searchsorted(csum, rank, side="left"),
+                    0).astype(jnp.int32)
+    part = jnp.where(live, rank - 1 - (csum[src] - reads[src]), 0)
+    return src, part, live
+
+
+def fan_out_deliver(ring: jax.Array, y: jax.Array, tick: jax.Array,
+                    fan_out, k: int) -> Tuple[jax.Array, jax.Array]:
+    """Push every spiking source's fan-out into the ``(D, n)`` ring:
+    ``ring[(tick + d) % D, target] += w``; returns ``(ring, blocks)``.
+
+    A spiking source's fan-out is read as whole rows of ``window``
+    entries (its ``count`` rounded up), and the tick's row reads are
+    taken ``k`` at a time: as many blocks as the tick needs (``blocks``,
+    a runtime count), so a block gathers ``k`` rows and scatters ``k *
+    window`` entries whatever the sources' degrees.  Entries past a
+    source's ``count``, and empty slots, are dropped by an out-of-range
+    ring row."""
+    depth, width = ring.shape[-2], fan_out.window
+    reads = jnp.where(y > 0, (fan_out.count + (width - 1)) // width, 0)
+    csum = jnp.cumsum(reads)
+    blocks = (csum[-1] + (k - 1)) // k
+    lane = jnp.arange(width, dtype=jnp.int32)
+
+    def block(b, ring):
+        src, part, live = read_block(csum, reads, b, k)
+        rows = fan_out.offset[src] + part
+        real = live[:, None] & (part[:, None] * width + lane[None, :]
+                                < fan_out.count[src][:, None])
+        tgt = fan_out.targets[rows]
+        w = fan_out.weights[rows]
+        d = fan_out.delays[rows].astype(jnp.int32)
+        row = jnp.where(real, jnp.mod(tick + d, depth), depth)
+        return ring.at[row, tgt].add(w, mode="drop")
+
+    return jax.lax.fori_loop(0, blocks, block, ring), blocks

@@ -33,12 +33,48 @@ class SNNParams:
         onto neurons (identity for the paper's networks where inputs drive
         input-layer neurons directly).
       lif: per-neuron :class:`LIFParams`.
+      drive: optional :class:`PoissonDrive`, the on-device background
+        drive of the event backend's ``fan_out`` strategy; None (the
+        leaf vanishes) everywhere else.  A fan-out fabric has no dense
+        ``w``: it passes ``w=None, c=None``.
     """
 
-    w: jax.Array
+    w: Optional[jax.Array]
     c: Optional[jax.Array]
     w_in: jax.Array
     lif: LIFParams
+    drive: Optional["PoissonDrive"] = None
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class PoissonDrive:
+    """Independent Poisson background input, drawn inside the tick.
+
+    Tick ``t`` adds ``poisson(fold_in(key, t), lam) * weight`` to every
+    neuron's arriving input -- the elementwise form of the diagonal
+    drive (``event_ext_diag``), with the counts made on the device from
+    the key and the absolute tick, so the host sends nothing per tick and
+    anyone holding the key can draw the same counts.
+
+    Attributes:
+      key: raw ``uint32[2]`` PRNG key.
+      lam: ``(n,)`` float32 mean events per neuron per tick.
+      weight: ``(n,)`` float32 input per event.
+    """
+
+    key: jax.Array
+    lam: jax.Array
+    weight: jax.Array
+
+    def counts(self, tick: jax.Array) -> jax.Array:
+        """This tick's ``(n,)`` int32 event counts."""
+        return jax.random.poisson(jax.random.fold_in(self.key, tick),
+                                  self.lam, dtype=jnp.int32)
+
+    def input(self, tick: jax.Array) -> jax.Array:
+        """This tick's ``(n,)`` drive, ``counts * weight``."""
+        return self.counts(tick).astype(self.weight.dtype) * self.weight
 
 
 @jax.tree_util.register_dataclass
@@ -49,6 +85,13 @@ class SNNState:
     ``delay_buf`` has shape ``(..., max_delay, n)``; slot ``(k % max_delay)``
     holds the spikes scheduled to arrive at tick ``k``. ``max_delay == 1``
     (the hardware default) degenerates to plain previous-tick delivery.
+
+    Under the event backend's ``fan_out`` strategy the same array is the
+    *postsynaptic* (dendritic) ring: row ``k % max_delay`` holds the
+    summed weights arriving at each neuron at tick ``k``.  Tick ``t``
+    reads and clears row ``t``, and each spiking source adds its fan-out
+    weights at rows ``(t + d) % max_delay`` -- per-synapse delays
+    ``1 <= d <= max_delay`` at a cost of spikes x fan-out.
     """
 
     lif: LIFState
@@ -56,9 +99,11 @@ class SNNState:
     tick: jax.Array
 
     @staticmethod
-    def zeros(batch_shape, n: int, max_delay: int = 1, dtype=jnp.float32) -> "SNNState":
+    def zeros(batch_shape, n: int, max_delay: int = 1, dtype=jnp.float32,
+              current: bool = False) -> "SNNState":
+        """``current=True`` adds the ``psc_exp`` synaptic current."""
         return SNNState(
-            lif=LIFState.zeros(batch_shape, n, dtype=dtype),
+            lif=LIFState.zeros(batch_shape, n, dtype=dtype, current=current),
             delay_buf=jnp.zeros(tuple(batch_shape) + (max_delay, n), dtype=dtype),
             tick=jnp.zeros((), dtype=jnp.int32),
         )

@@ -268,3 +268,27 @@ _DEFAULT = MetricsRegistry()
 def get_registry() -> MetricsRegistry:
     """The process-wide default registry (CLIs; servers make their own)."""
     return _DEFAULT
+
+
+def record_fan_out(registry: MetricsRegistry, *,
+                   entries: Optional[int] = None,
+                   padding_fraction: Optional[float] = None,
+                   syn_events: Optional[float] = None) -> None:
+    """The resident fan-out's metrics (event backend, ``fan_out``):
+    gauges ``snn_fanout_entries`` (real entries, padding excluded) and
+    ``snn_fanout_padding_fraction`` (share of the padded layout that is
+    padding), set at build; counter ``snn_synaptic_events_total``, the
+    fan-out entries delivered, brought up to the running total
+    ``syn_events`` the telemetry reports."""
+    if entries is not None:
+        registry.gauge("snn_fanout_entries",
+                       "fan-out entries resident, padding excluded").set(
+                           entries)
+    if padding_fraction is not None:
+        registry.gauge("snn_fanout_padding_fraction",
+                       "share of the padded fan-out layout that is "
+                       "padding").set(padding_fraction)
+    if syn_events is not None:
+        c = registry.counter("snn_synaptic_events_total",
+                             "fan-out entries delivered into the delay ring")
+        c.inc(max(0.0, float(syn_events) - c.value()))

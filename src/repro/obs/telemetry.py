@@ -59,6 +59,14 @@ class TickTelemetry:
         frozen) -- the L1 norm of the whole weight-update stream.
       dw_sq: accumulated ``sum dw^2``; ``sqrt`` of it is the L2 norm of
         the update stream.
+      syn_events: event backend ``fan_out`` strategy only (None
+        elsewhere, so the leaf vanishes): fan-out entries delivered into
+        the delay ring, padding excluded.
+      spill_blocks: ``fan_out`` only: blocks of row reads run beyond the
+        first of a tick (a tick whose spiking sources need more reads than
+        the static budget runs further blocks; no spike is ever dropped).
+      pop_spikes: ``fan_out`` only: ``(..., n_pops)`` spikes emitted per
+        population.
     """
 
     ticks: jax.Array
@@ -70,16 +78,26 @@ class TickTelemetry:
     policy_dense: jax.Array
     dw_l1: jax.Array
     dw_sq: jax.Array
+    syn_events: Optional[jax.Array] = None
+    spill_blocks: Optional[jax.Array] = None
+    pop_spikes: Optional[jax.Array] = None
 
     @staticmethod
-    def zeros(batch_shape=()) -> "TickTelemetry":
+    def zeros(batch_shape=(), n_pops: Optional[int] = None,
+              ) -> "TickTelemetry":
+        """``n_pops`` adds the ``fan_out`` counters."""
         shape = tuple(batch_shape)
         f = lambda: jnp.zeros(shape, jnp.float32)
         i = lambda: jnp.zeros(shape, jnp.int32)
+        fan = n_pops is not None
         return TickTelemetry(
             ticks=jnp.zeros(shape, jnp.int32), spikes=f(), v_sum=f(),
             v_max=f(), ref_sum=f(), overflow=i(), policy_dense=i(),
-            dw_l1=f(), dw_sq=f())
+            dw_l1=f(), dw_sq=f(),
+            syn_events=f() if fan else None,
+            spill_blocks=i() if fan else None,
+            pop_spikes=jnp.zeros(shape + (n_pops,), jnp.float32)
+            if fan else None)
 
     def accumulate(
         self,
@@ -87,6 +105,7 @@ class TickTelemetry:
         *,
         overflow_inc: Optional[jax.Array] = None,
         policy_inc: Optional[jax.Array] = None,
+        fan_out_inc: Optional[tuple] = None,
     ) -> "TickTelemetry":
         """Fold one tick's outputs in (pure reductions over the neuron axis).
 
@@ -97,6 +116,8 @@ class TickTelemetry:
           policy_inc: optional batch-shaped i32 increment (event backend:
             1 on ticks the adaptive knee routed to the dense arm for speed
             -- counted separately from ``overflow_inc``).
+          fan_out_inc: optional ``(syn_events, spill_blocks, pop_spikes)``
+            increments of the ``fan_out`` counters.
 
         The plasticity hook's weight delta folds in separately, through
         :meth:`fold_dw`, on the ticks where the hook runs.
@@ -122,7 +143,7 @@ class TickTelemetry:
         policy_dense = self.policy_dense
         if policy_inc is not None:
             policy_dense = policy_dense + policy_inc
-        return TickTelemetry(
+        out = TickTelemetry(
             ticks=self.ticks + 1,
             spikes=self.spikes + s_y,
             v_sum=self.v_sum + s_v / n,
@@ -131,7 +152,17 @@ class TickTelemetry:
             overflow=overflow,
             policy_dense=policy_dense,
             dw_l1=self.dw_l1,
-            dw_sq=self.dw_sq)
+            dw_sq=self.dw_sq,
+            syn_events=self.syn_events,
+            spill_blocks=self.spill_blocks,
+            pop_spikes=self.pop_spikes)
+        if fan_out_inc is not None:
+            syn, spill, pops = fan_out_inc
+            out = dataclasses.replace(
+                out, syn_events=self.syn_events + syn,
+                spill_blocks=self.spill_blocks + spill,
+                pop_spikes=self.pop_spikes + pops)
+        return out
 
     def fold_dw(self, dw: jax.Array) -> "TickTelemetry":
         """Fold one plasticity tick's committed weight delta
@@ -157,7 +188,7 @@ class TickTelemetry:
         spikes = float(leaf(self.spikes).sum())
         batch = max(1, int(leaf(self.spikes).size))
         denom = max(1.0, ticks * n * batch)
-        return {
+        out = {
             "ticks": ticks,
             "spikes": spikes,
             "spike_rate": spikes / denom,
@@ -170,3 +201,9 @@ class TickTelemetry:
             "dw_l1": float(leaf(self.dw_l1).sum()),
             "dw_l2": float(np.sqrt(leaf(self.dw_sq).sum())),
         }
+        if self.syn_events is not None:
+            out["syn_events"] = float(leaf(self.syn_events).sum())
+            out["spill_blocks"] = float(leaf(self.spill_blocks).sum())
+            out["pop_spikes"] = leaf(self.pop_spikes).reshape(
+                -1, self.pop_spikes.shape[-1]).sum(axis=0).tolist()
+        return out

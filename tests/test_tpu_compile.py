@@ -231,3 +231,33 @@ def test_sharded_tick_on_four_chips(topo):
     per_device = c.memory_analysis().argument_size_in_bytes
     assert w_bytes // d <= per_device < w_bytes // d + 2 ** 24
     assert c.as_text().count("all-gather(") == 1
+
+
+def test_microcircuit_chunk_at_full_size(one_chip):
+    """The Potjans-Diesmann microcircuit's 100-tick request program at
+    its published size (77,169 psc_exp neurons, a resident fan-out of
+    about 3.3e8 entries, a 64-deep ring, the Poisson drive) compiles for
+    one v5e chip and fits it, the ring delivery a scatter-add (no
+    kernel)."""
+    from repro.configs.pd_microcircuit import FANOUT_WINDOW, Microcircuit
+    from repro.core.connectivity import FanOut
+
+    mc = Microcircuit()
+    n, rows, window = mc.n, 660_000, FANOUT_WINDOW
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    fo = FanOut(targets=arr((rows, window), I32),
+                weights=arr((rows, window), F32),
+                delays=arr((rows, window), jnp.uint8), offset=arr((n,), I32),
+                count=arr((n,), I32), pop_starts=arr((9,), I32))
+    key = jnp.zeros((2,), jnp.uint32)
+    params = jax.eval_shape(lambda: mc.params(key))
+    carry = jax.eval_shape(lambda: TickCarry(
+        state=mc.initial_state(key), telem=TickTelemetry.zeros((), n_pops=8)))
+    engine = TickEngine(mc.engine_options())
+    c = _compile(lambda p, s, f: engine.chunk(p, s, None, 100, neighbors=f),
+                 _spec(params, one_chip), _spec(carry, one_chip), fo)
+    mem = c.memory_analysis()
+    assert 2.9e9 < mem.argument_size_in_bytes < 3.2e9
+    assert mem.temp_size_in_bytes < 2 ** 30
+    text = c.as_text()
+    assert "scatter(" in text and KERNEL not in text
