@@ -7,6 +7,8 @@ One process, phases in order, one JSON line each on stdout:
 * **A -- the paper's fabric.**  ``--arch snn`` (n_max=74, 8 resident
   tenants of mixed topology, one of them plastic, 4 slots) served by
   :meth:`SNNServer.serve_continuous`, built as the serve CLI builds it.
+  The line gives the bytes each continuous program (slot refill, chunk)
+  updates in place, ``alias_bytes``; both must be non-zero.
 * **B -- the full-width dense fabric.**  ``--arch snn-fused`` (n_max=4096,
   ``pallas_fused``) served the same way; sparse tenants ride the second,
   event, program.
@@ -260,6 +262,12 @@ def phase_serve(tag: str, arch: str, smoke: bool, clock: CompileClock,
         "compile_s": compile_s,
         "recompiles_after_warmup": stats["recompiles_after_warmup"],
     }
+    line["alias_bytes"] = {
+        p: server.program_alias_bytes(default_backend, p)
+        for p in ("fill", "chunk")}
+    _check(all(line["alias_bytes"].values()),
+           f"a continuous program copies its stacked inputs: "
+           f"{line['alias_bytes']}")
     line["parity"] = _served_parity(server, reqs, w0)
     _check(backends.get(default_backend, 0) > 0,
            f"no request rode the {default_backend!r} program")

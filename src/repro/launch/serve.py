@@ -319,6 +319,15 @@ def pad_tenant_params(params, n_max: int):
     return SNNParams(w=p2(params.w), c=p2(params.c), w_in=p2(params.w_in), lif=lif)
 
 
+def _arg_specs(args):
+    """Shape-only stand-ins for a program's array arguments, to lower it
+    again later: they hold no reference to arrays a donating call
+    deletes."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding,
+                                       weak_type=a.weak_type), args)
+
+
 class SNNServer:
     """Slot-batched multi-tenant SNN serving on one compiled tick program.
 
@@ -410,7 +419,8 @@ class SNNServer:
         self._compiles: Dict[str, int] = {}   # per-program, TRACE time only
         self._runs: Dict[str, object] = {}
         self._chunk_runs: Dict[tuple, object] = {}
-        # (backend, chunk) -> shapes of the first dispatched argument list
+        # (backend, chunk) or ("fill", backend) -> shapes of the first
+        # dispatched argument list
         self._chunk_arg_specs: Dict[tuple, tuple] = {}
         self._fresh_zeros = None
         self._tenant_obs: Dict[str, Dict] = {}  # accumulated telemetry
@@ -429,6 +439,10 @@ class SNNServer:
             "snn_chunks_total",
             "continuous-admission chunks run, by resident program",
             ("backend",))
+        self._c_refills = r.counter(
+            "snn_slot_refills_total",
+            "slot refills written in place into the stacked inputs of a "
+            "continuous group's programs, by resident program", ("backend",))
         self._c_spikes = r.counter(
             "snn_spikes_out_total", "rate-decoded output spikes")
         self._c_slot_ticks = r.counter(
@@ -481,23 +495,50 @@ class SNNServer:
     def _chunk_run_for(self, backend: str, chunk: int):
         """The jitted chunked step -- one resident program per
         (backend, chunk size), traced once; slot refills only rewrite
-        its array arguments."""
+        its array arguments.
+
+        ``carry`` and ``counts_acc`` are donated: the step updates the
+        stacked carry (its ``(S, N, N)`` weights and eligibility traces
+        included) and the running counts in place instead of copying
+        them at entry, so the caller must not touch the arrays it passed
+        once the call is made.  ``params`` and ``plastic_c`` are read
+        only, and the next chunk reads them again."""
         key = (backend, int(chunk))
         if key not in self._chunk_runs:
             self._engines.setdefault(backend, self._mk_engine(backend))
             self._chunk_runs[key] = jax.jit(
-                functools.partial(self._chunk_fn, backend, int(chunk)))
+                functools.partial(self._chunk_fn, backend, int(chunk)),
+                donate_argnames=("carry", "counts_acc"))
         return self._chunk_runs[key]
+
+    def _compiled(self, key: tuple):
+        """A resident program compiled for the argument shapes
+        :meth:`serve_continuous` first dispatched it with (a ``KeyError``
+        if it never ran).  Compiles, so keep it out of timed code."""
+        return self._chunk_runs[key].lower(
+            *self._chunk_arg_specs[key]).compile()
 
     def chunk_program_text(self, backend: str,
                            chunk: Optional[int] = None) -> str:
-        """Compiled text of the resident chunk program for ``backend``,
-        lowered for the argument shapes :meth:`serve_continuous` first
-        dispatched it with (a ``KeyError`` if it never ran).  For checks
-        of what the program holds, e.g. which Pallas kernels it calls."""
+        """Compiled text of the resident chunk program for ``backend``.
+        For checks of what the program holds, e.g. which Pallas kernels
+        it calls."""
         key = (backend, int(self.chunk_ticks if chunk is None else chunk))
-        return self._chunk_runs[key].lower(
-            *self._chunk_arg_specs[key]).compile().as_text()
+        return self._compiled(key).as_text()
+
+    def program_alias_bytes(self, backend: str, program: str) -> int:
+        """Bytes of input that the compiled ``program`` (``"fill"``, the
+        slot refill, or ``"chunk"``, at the server's chunk size) for
+        ``backend`` updates in place: its donated inputs that alias an
+        output.  0 means every call writes whole new copies of them."""
+        if program == "fill":
+            key = ("fill", backend)
+        elif program == "chunk":
+            key = (backend, self.chunk_ticks)
+        else:
+            raise ValueError(f"program must be 'fill' or 'chunk', "
+                             f"got {program!r}")
+        return int(self._compiled(key).memory_analysis().alias_size_in_bytes)
 
     # -- tenant registry ---------------------------------------------------
 
@@ -989,7 +1030,12 @@ class SNNServer:
         tenant image into slot ``i`` of the stacked program inputs in a
         single compiled call (one trace per backend; an eager
         ``.at[i].set`` per leaf costs ~1 ms each, which would dominate
-        the chunk loop)."""
+        the chunk loop).
+
+        The stacked inputs are donated, so the refill writes the slot's
+        slices in place rather than copying every ``(S, N, N)`` stack;
+        the image (tenant registers, the shared fresh-carry zeros) is
+        only read."""
         key = ("fill", backend)
         if key not in self._chunk_runs:
             def _fill(stacked, image, i):
@@ -998,7 +1044,7 @@ class SNNServer:
                 return jax.tree.map(lambda a, b: a.at[i].set(b),
                                     stacked, image)
 
-            self._chunk_runs[key] = jax.jit(_fill)
+            self._chunk_runs[key] = jax.jit(_fill, donate_argnums=0)
         return self._chunk_runs[key]
 
     @staticmethod
@@ -1138,7 +1184,14 @@ class SNNServer:
         lives host-side; the compiled step sees only arrays. Refill
         writes one slot's registers/carry via ``.at[i].set`` -- values,
         not shapes, so the program never retraces (pinned:
-        ``recompiles_after_warmup == 0`` across refills)."""
+        ``recompiles_after_warmup == 0`` across refills).
+
+        Both programs donate the stacked arrays they update (the refill
+        all of them, the chunk the carry and the counts), so the stacked
+        arrays live only in this function's locals, rebound to each
+        call's outputs. A read of one slot (``counts_acc[i]``,
+        ``carry_s.w[i]``) is a new array and outlives the next donation;
+        ``_chunk_arg_specs`` keeps shapes only."""
         S, N = self.slots, self.n_max
         pending = pending_map.setdefault(backend, deque())
         run = self._chunk_run_for(backend, chunk)
@@ -1190,8 +1243,12 @@ class SNNServer:
                          t.fan_idx if ev else None, t.fan_mask if ev else None)
                 stacked = (params_s, carry_s, plastic_c_s, counts_acc,
                            fan_idx_s, fan_mask_s)
+                if ("fill", backend) not in self._chunk_arg_specs:
+                    self._chunk_arg_specs[("fill", backend)] = (
+                        *_arg_specs((stacked, image)), i)
                 (params_s, carry_s, plastic_c_s, counts_acc,
                  fan_idx_s, fan_mask_s) = fill_run(stacked, image, i)
+                self._c_refills.inc(backend=backend)
 
         def retire(i: int, now: float, row: Optional[np.ndarray] = None,
                    tel=None) -> None:
@@ -1269,10 +1326,7 @@ class SNNServer:
                 if backend == "event":
                     args += (fan_idx_s, fan_mask_s)
                 if (backend, chunk) not in self._chunk_arg_specs:
-                    self._chunk_arg_specs[(backend, chunk)] = jax.tree.map(
-                        lambda a: jax.ShapeDtypeStruct(
-                            a.shape, a.dtype, sharding=a.sharding,
-                            weak_type=a.weak_type), args)
+                    self._chunk_arg_specs[(backend, chunk)] = _arg_specs(args)
             # The dispatch only: counts stay on device, so this span does
             # NOT wait for the chunk to execute -- consecutive chunks
             # pipeline, and the device queue only drains at a retire

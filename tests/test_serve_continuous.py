@@ -7,6 +7,8 @@ mixing dense and event tenants, and handling the admission edges
 (zero-tick budgets, unknown tenants, feeder-streamed late arrivals).
 """
 import functools
+import re
+import warnings
 from collections import deque
 
 import jax
@@ -208,6 +210,115 @@ class TestZeroRecompile:
         assert sc.compiles == warm
         with pytest.raises(KeyError):
             sc.chunk_program_text("pallas_fused")
+
+
+def _entry_param_copies(text: str, shape: str):
+    """(parameters of ``shape``, those of them the entry computation of a
+    compiled module copies whole)."""
+    entry = text[text.index("\nENTRY"):]
+    params = re.findall(
+        r"%(\S+) = " + re.escape(shape) + r"\{[\d,]*\} parameter\(", entry)
+    return params, [p for p in params if f"copy(%{p})" in entry]
+
+
+def _serve_erroring_on_warnings(server, reqs, **kw):
+    """Serve with every warning raised as an error: a donated buffer the
+    program cannot reuse warns ("donated buffers were not usable")."""
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        return server.serve_continuous(reqs, **kw)
+
+
+class TestInPlace:
+    """The refill and chunk programs donate the stacked operands they
+    update, so neither copies a whole ``(S, N, N)`` stack per call."""
+
+    @pytest.fixture(scope="class")
+    def served(self):
+        _, sc, names = _twin_servers()
+        reqs = make_demo_requests(sc, names, 16, seed=1)
+        _serve_erroring_on_warnings(sc, reqs)
+        return sc, reqs
+
+    @pytest.mark.parametrize("backend", ["jnp", "event"])
+    def test_fill_updates_every_stack_in_place(self, served, backend):
+        sc, _ = served
+        S, N = sc.slots, sc.n_max
+        stack = S * N * N * 4
+        # params.w, .c, .w_in, carry.w, carry.plast.elig, plastic_c
+        assert sc.program_alias_bytes(backend, "fill") >= 6 * stack
+        text = sc._compiled(("fill", backend)).as_text()
+        params, copied = _entry_param_copies(text, f"f32[{S},{N},{N}]")
+        assert len(params) == 6
+        assert not copied
+
+    @pytest.mark.parametrize("backend", ["jnp", "event"])
+    def test_chunk_enters_carry_in_place(self, served, backend):
+        sc, _ = served
+        S, N = sc.slots, sc.n_max
+        # carry.w and carry.plast.elig, plus the (S, N) running counts
+        want = 2 * S * N * N * 4 + S * N * 4
+        assert sc.program_alias_bytes(backend, "chunk") >= want
+        params, copied = _entry_param_copies(
+            sc.chunk_program_text(backend), f"f32[{S},{N},{N}]")
+        carry = [p for p in params if p.startswith("carry")]
+        assert len(carry) == 2
+        assert not [p for p in copied if p.startswith("carry")]
+
+    def test_asking_traces_nothing_and_rejects_unknown_programs(
+            self, served):
+        sc, _ = served
+        warm = sc.compiles
+        sc.program_alias_bytes("jnp", "fill")
+        sc.program_alias_bytes("jnp", "chunk")
+        assert sc.compiles == warm
+        with pytest.raises(ValueError, match="program"):
+            sc.program_alias_bytes("jnp", "wave")
+
+    def test_refills_counted_per_program(self, served):
+        """Each backend's group builds its stack with the first fill and
+        refills in place for every later request."""
+        sc, reqs = served
+        refills = sc.registry.get("snn_slot_refills_total")
+        for backend in ("jnp", "event"):
+            n = sum(sc.tenants[r.tenant].backend == backend for r in reqs)
+            assert n > 1
+            assert refills.value(backend=backend) == n - 1
+
+
+class TestDonationSafe:
+    """Host reads that straddle a donating call: a plastic write-back read
+    from a carry the next refill donates, a zero-budget retire straight
+    after a refill, and a second call on the same server."""
+
+    BATCHES = (
+        [("dense-7", 4), ("dense-3", 0), ("dense-7", 4), ("layered-0", 0),
+         ("dense-7", 0), ("dense-7", 8), ("ring-1", 6)],
+        [("dense-7", 5), ("sparse-2", 3), ("dense-7", 0), ("dense-3", 7),
+         ("sparse-6", 0), ("sparse-2", 9), ("dense-7", 9)],
+    )
+
+    @pytest.mark.parametrize("chunk", [1, 4])
+    def test_bit_exact_vs_wave_across_refills_and_calls(self, chunk):
+        sw, sc, _ = _twin_servers()
+        for seed, spec in enumerate(self.BATCHES):
+            reqs_w = _requests(sw, spec, seed=seed)
+            reqs_c = _requests(sc, spec, seed=seed)
+            sw.serve(reqs_w)
+            _serve_erroring_on_warnings(sc, reqs_c, chunk_ticks=chunk)
+            for a, b in zip(reqs_w, reqs_c):
+                assert a.pred == b.pred
+                np.testing.assert_array_equal(a.counts, b.counts)
+            for n in sw.tenants:
+                np.testing.assert_array_equal(
+                    np.asarray(sw.tenants[n].params.w),
+                    np.asarray(sc.tenants[n].params.w))
+            # The continuous path observes only slots that ran a tick, so
+            # a tenant seen only at budget 0 is in the wave report alone.
+            rep_w, rep_c = sw.tenant_report(), sc.tenant_report()
+            for n in rep_c:
+                assert rep_w[n]["dw_l1"] == rep_c[n]["dw_l1"]
+        assert sc.tenant_report()["dense-7"]["dw_l1"] > 0
 
 
 class TestAdmissionEdges:
