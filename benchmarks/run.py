@@ -64,8 +64,8 @@ def main() -> None:
         print(report.summary(), flush=True)
 
     from benchmarks import (
-        bench_iris, bench_latency, bench_mnist, bench_serve, bench_snn_scale,
-        bench_stdp, bench_uart,
+        bench_iris, bench_latency, bench_mnist, bench_snn_scale, bench_stdp,
+        bench_uart,
     )
     from repro.obs import profile
 
@@ -74,7 +74,6 @@ def main() -> None:
         ("latency", bench_latency.run),
         ("snn_scale", lambda: bench_snn_scale.run(fast=args.fast)),
         ("stdp", bench_stdp.run),
-        ("serve", lambda: bench_serve.run(fast=args.fast)),
     ]
     if not args.fast:
         benches += [("iris", bench_iris.run), ("mnist", bench_mnist.run)]

@@ -14,8 +14,8 @@ lint).  :func:`iter_programs` yields the full shipped matrix:
   and the event backend's ``fan_out`` chunk (``psc_exp`` neurons,
   per-synapse delays into the ring, on-device Poisson drive) with
   telemetry on and off;
-* serve programs -- the wave program (dense + event), the continuous
-  chunked step, and the slot-refill register-download program;
+* serve programs -- the continuous chunked step (dense + event) and
+  the slot-refill register-download program;
 * kernel launches -- each Pallas kernel's descriptor at a
   representative padded shape (what :mod:`repro.kernels.ops` would
   launch on TPU; CPU runs interpret mode, but the descriptor is
@@ -236,7 +236,7 @@ def _fan_out_program(telemetry: bool) -> Program:
 
 
 # ---------------------------------------------------------------------------
-# Serve programs (wave / chunk / refill)
+# Serve programs (chunk / refill)
 # ---------------------------------------------------------------------------
 
 def _demo_server(event: bool):
@@ -267,30 +267,11 @@ def _demo_server(event: bool):
     return server, t
 
 
-def _serve_wave_program(event: bool) -> Program:
-    from repro.launch.serve import ServeRequest
+def _serve_chunk_program(event: bool) -> Program:
+    import jax
 
     server, t = _demo_server(event)
     backend = t.backend
-    reqs = [ServeRequest(rid=i, tenant="demo",
-                         ext=np.zeros((4, t.n_in), np.float32), n_ticks=4)
-            for i in range(server.slots)]
-    args = server._assemble(reqs)
-    # _run_for registers the backend engine and returns the jitted wave
-    # program -- the same object serving runs (make_jaxpr recurses into
-    # the pjit eqn, so the analysis sees the whole body).
-    fn = server._run_for(backend)
-    # The wave vmaps the rollout over slots, so every W*C product carries
-    # a leading slot axis -- the rank-2 hoist grep does not apply (the
-    # tick programs above pin the hoist contract for each backend).
-    return Program(name=f"serve/wave/{backend}", fn=fn, args=args,
-                   n=server.n_max, hoist=jaxpr_rules.HOIST_SKIP)
-
-
-def _serve_chunk_program() -> Program:
-    import jax
-
-    server, t = _demo_server(False)
     S, N, chunk = server.slots, server.n_max, 2
     fresh = server._fresh_slot_carry(t)
     bcast = lambda x: jax.tree.map(
@@ -303,9 +284,16 @@ def _serve_chunk_program() -> Program:
             jnp.zeros((S,), jnp.int32),
             jnp.zeros((S,), jnp.bool_),
             jnp.zeros((S, N), jnp.float32),
-            None, None)
-    fn = functools.partial(server._chunk_fn, "jnp", chunk)
-    return Program(name="serve/chunk/jnp", fn=fn, args=args,
+            bcast(t.fan_idx) if event else None,
+            bcast(t.fan_mask) if event else None)
+    # _chunk_run_for registers the backend engine and returns the jitted
+    # chunk program -- the same object serving runs (make_jaxpr recurses
+    # into the pjit eqn, so the analysis sees the whole body).
+    fn = server._chunk_run_for(backend, chunk)
+    # The step vmaps the tick over slots, so every W*C product carries a
+    # leading slot axis -- the rank-2 hoist grep does not apply (the
+    # tick programs above pin the hoist contract for each backend).
+    return Program(name=f"serve/chunk/{backend}", fn=fn, args=args,
                    n=N, hoist=jaxpr_rules.HOIST_SKIP)
 
 
@@ -420,8 +408,7 @@ def program_names() -> Tuple[str, ...]:
              for tel in ("notelem", "telem")]
     names += ["tick/sharded/frozen/notelem", "tick/sharded/learning/telem"]
     names += ["tick/fan_out/psc_exp/notelem", "tick/fan_out/psc_exp/telem"]
-    names += ["serve/wave/jnp", "serve/wave/event", "serve/chunk/jnp",
-              "serve/refill/jnp"]
+    names += ["serve/chunk/jnp", "serve/chunk/event", "serve/refill/jnp"]
     names += [f"kernel/{reg}" for reg, _ in kernel_launches()]
     return tuple(names)
 
@@ -437,12 +424,8 @@ def build_program(name: str) -> Program:
         if backend == "sharded":
             return _tick_sharded_program(tag == "learning", tel == "telem")
         return _tick_program(backend, tag == "learning", tel == "telem")
-    if name == "serve/wave/jnp":
-        return _serve_wave_program(False)
-    if name == "serve/wave/event":
-        return _serve_wave_program(True)
-    if name == "serve/chunk/jnp":
-        return _serve_chunk_program()
+    if name in ("serve/chunk/jnp", "serve/chunk/event"):
+        return _serve_chunk_program(parts[-1] == "event")
     if name == "serve/refill/jnp":
         return _serve_refill_program()
     if parts[0] == "kernel":

@@ -2,7 +2,7 @@
 
 ``n_neurons`` here is the *fabric* size ``n_max`` -- every tenant network
 is zero-padded onto it (padded neurons carry an unreachable threshold, so
-they never spike and never learn). ``n_ticks`` is the per-wave tick
+they never spike and never learn). ``n_ticks`` is the per-request tick
 budget ceiling; requests may ask for less and are masked at decode.
 """
 import dataclasses

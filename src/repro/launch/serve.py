@@ -1,6 +1,6 @@
 """Batched serving driver (the paper's kind: an inference platform).
 
-Two server flavors share one shape of loop:
+Two server flavors:
 
 * :class:`WaveServer` -- the LM model zoo: requests are grouped into
   waves of ``slots``; each wave left-pads prompts to a common length,
@@ -10,10 +10,13 @@ Two server flavors share one shape of loop:
 * :class:`SNNServer` -- the SNN processor itself, multi-tenant: S
   independent *networks* (each its own ``W/C/thresholds/leak`` register
   image, loaded via :func:`repro.core.network.params_from_registers`)
-  ride one compiled tick program, vmapped over a slot axis. The slot
-  axis is the TPU restatement of time-sharing the mux fabric
-  (DESIGN.md §8): swapping a tenant in = rewriting a slot's registers,
-  never recompiling.
+  ride one compiled chunk program, vmapped over a slot axis, and are
+  admitted continuously: a slot whose request spent its tick budget is
+  refilled from the queue between chunks. The slot axis is the TPU
+  restatement of time-sharing the mux fabric (DESIGN.md §8): swapping
+  a tenant in = rewriting a slot's registers, never recompiling. Each
+  request is answered as if it ran alone on the core engine (the
+  per-request reference in tests/serve_reference.py).
 
 Both mirror how the FPGA serves: one resident "fabric" (compiled
 program), per-request state swapped in registers -- and like the FPGA,
@@ -86,7 +89,7 @@ class ServeResult:
     """Immutable completion record, one per served request.
 
     ``ttft_s`` is measured from *enqueue* (``t_submit``), not from
-    wave/chunk start -- under continuous admission a queued request's
+    slot-fill start -- under continuous admission a queued request's
     wait is real latency its caller observed.
     """
 
@@ -272,13 +275,13 @@ class Tenant:
     ``n`` carry an unreachable threshold (silent forever) and a zeroed
     connection/plastic mask (can never learn). ``plastic_c`` gates the
     learning hook per synapse: all-zero for frozen tenants, so their
-    weights come back *bit-identical* from every wave.
+    weights come back *bit-identical* from every request.
 
     ``backend`` is the tick program this tenant rides: the server's
     default, or ``"event"`` when the tenant's topology is sparse enough
     to clear the server's ``event_density`` threshold (then ``fan_idx``
     / ``fan_mask`` hold its padded fan-in lists, fabric-shaped
-    ``(n_max, event_cap)`` so every event-wave slot stacks to one static
+    ``(n_max, event_cap)`` so every event slot stacks to one static
     shape).
     """
 
@@ -329,23 +332,23 @@ def _arg_specs(args):
 
 
 class SNNServer:
-    """Slot-batched multi-tenant SNN serving on one compiled tick program.
+    """Slot-batched multi-tenant SNN serving on one compiled chunk program.
 
     S slots x one :class:`~repro.core.engine.TickEngine`, vmapped over the
-    slot axis: every wave runs S independent networks -- heterogeneous
+    slot axis: every chunk runs S independent networks -- heterogeneous
     ``C`` topologies, thresholds, leaks, even a mix of frozen and plastic
     tenants -- through ONE jitted program of static shape
-    ``(slots, max_ticks, n_max)``. Admission is wave-batched like the LM
-    :class:`WaveServer`; per-request tick budgets are runtime masks, so
-    neither budgets nor tenant swaps ever retrace (``self.compiles``
-    counts traces and must stay at 1 after warmup).
+    ``(slots, chunk_ticks, n_max)`` per backend in use, plus one slot
+    refill program per backend. Admission is continuous
+    (:meth:`serve_continuous`); per-request tick budgets, offsets and
+    tenant registers are runtime data, so neither budgets nor tenant
+    swaps ever retrace (``recompiles_after_warmup`` stays at 0).
 
-    Every wave runs the *learning* tick body (the engine's plasticity
-    hook); frozen tenants pass an all-zero ``plastic_c``, which the STDP
-    rule turns into an exact no-op -- one datapath for inference and
-    learning, as NeuroCoreX does in silicon. The continuous chunk program
-    (:meth:`serve_continuous`) keeps that one datapath but runs the hook
-    only on the slot-ticks that learn, bit-identical to the wave path.
+    One datapath for inference and learning, as NeuroCoreX does in
+    silicon: the chunk program runs the tick body on every slot and the
+    plasticity hook only on the slot-ticks that learn. Each request's
+    counts, and each plastic tenant's learned weights, equal those of
+    the request run alone on the core engine, bit for bit.
     """
 
     def __init__(self, *, n_max: int, slots: int = 8, max_ticks: int = 32,
@@ -365,11 +368,11 @@ class SNNServer:
           keep the default program.  None disables the event program.
         event_cap: fan-in cap (static shape) of the event program's padded
           neighbor lists; defaults to ``n_max // 4``.  One cap for the
-          whole server keeps the event wave's shapes static, so tenant
+          whole server keeps the event program's shapes static, so tenant
           swaps never retrace (a tenant whose in-degree exceeds the cap
           simply stays on the dense program -- never truncated).
         telemetry: thread :class:`~repro.obs.telemetry.TickTelemetry`
-          through every wave's scan carry (static flag -- the resident
+          through every slot's carry (static flag -- the resident
           programs are traced with it once, never retraced). Feeds
           :meth:`tenant_report` and the spike/overflow/weight-delta
           metrics; False serves the exact telemetry-free programs.
@@ -417,7 +420,6 @@ class SNNServer:
         self._engines = {backend: self.engine}
         self.tenants: Dict[str, Tenant] = {}
         self._compiles: Dict[str, int] = {}   # per-program, TRACE time only
-        self._runs: Dict[str, object] = {}
         self._chunk_runs: Dict[tuple, object] = {}
         # (backend, chunk) or ("fill", backend) -> shapes of the first
         # dispatched argument list
@@ -433,8 +435,6 @@ class SNNServer:
         self._c_rej_reason = r.counter(
             "snn_admission_rejections_total",
             "admission rejections, by reason", ("reason",))
-        self._c_waves = r.counter(
-            "snn_waves_total", "waves run, by resident program", ("backend",))
         self._c_chunks = r.counter(
             "snn_chunks_total",
             "continuous-admission chunks run, by resident program",
@@ -462,7 +462,7 @@ class SNNServer:
             "event-backend ticks the adaptive knee routed dense for speed")
         self._c_dw = r.counter(
             "snn_weight_delta_l1_total", "summed |dw| applied by plasticity")
-        self._g_queue = r.gauge("snn_queue_depth", "requests awaiting a wave")
+        self._g_queue = r.gauge("snn_queue_depth", "requests awaiting a slot")
         self._g_busy = r.gauge(
             "snn_slots_busy", "slots holding a live request right now")
         self._g_goodput = r.gauge(
@@ -472,25 +472,16 @@ class SNNServer:
             "useful (in-budget) slot-ticks per second of the last serve call")
         self._h_ttft = r.histogram(
             "snn_ttft_seconds", "enqueue-to-first-output latency")
-        self._h_wave = r.histogram(
-            "snn_wave_seconds", "wave wall time, by resident program",
-            ("backend",))
         self._h_queue_wait = r.histogram(
             "snn_queue_wait_seconds",
             "enqueue-to-slot-fill wait under continuous admission")
 
     @property
     def compiles(self) -> int:
-        """Total trace count across the server's resident programs (one
-        per backend in use; tenant/slot churn must never add to it)."""
+        """Total trace count across the server's resident programs (a
+        chunk program per (backend, chunk size) in use and a refill
+        program per backend; tenant/slot churn must never add to it)."""
         return sum(self._compiles.values())
-
-    def _run_for(self, backend: str):
-        if backend not in self._runs:
-            self._engines.setdefault(backend, self._mk_engine(backend))
-            self._runs[backend] = jax.jit(
-                functools.partial(self._wave_fn, backend))
-        return self._runs[backend]
 
     def _chunk_run_for(self, backend: str, chunk: int):
         """The jitted chunked step -- one resident program per
@@ -571,8 +562,8 @@ class SNNServer:
             from repro.core import dispatch_policy
 
             # Admission-time dispatch plan (host side, concrete topology):
-            # vmap_safe because the wave vmaps the rollout over slots (the
-            # topk path's lax.cond would lower to a both-arms select);
+            # vmap_safe because the chunk program vmaps the tick over slots
+            # (the topk path's lax.cond would lower to a both-arms select);
             # prefer_density is the operator contract -- at or below the
             # server's threshold a fabric whose fan-in fits the shared cap
             # rides the event program regardless of the modeled cost.
@@ -594,57 +585,7 @@ class SNNServer:
         self.tenants[name] = t
         return t
 
-    # -- the one compiled program -----------------------------------------
-
-    def _wave_fn(self, backend, params, ext_seq, plastic_c, rewards, budget,
-                 fan_idx=None, fan_mask=None):
-        """(slot-batched params, (S,T,N) ext, (S,N,N) mask, (S,T) rewards,
-        (S,) budgets[, (S,N,cap) fan-in lists]) -> ((S,N) masked spike
-        counts, (S,N,N) new weights).
-
-        The per-slot budget gates BOTH the rate decode (ticks >= budget
-        don't count) and the plasticity hook (``learn_until``): a request
-        never learns past its own tick budget, so the persisted weights
-        don't depend on the server's ``max_ticks`` ceiling.
-
-        Event waves vmap the engine's fan-in gather path -- pure gathers,
-        no data-dependent control flow, so the slot axis lowers exactly
-        like the dense program's.
-
-        With ``telemetry`` on, a per-slot
-        :class:`~repro.obs.telemetry.TickTelemetry` rides the scan carry
-        and is appended to the return tuple; it covers the full
-        ``max_ticks`` rollout (ticks past a request's budget included --
-        they run, they just don't count or learn)."""
-        from repro.core.network import SNNState
-        from repro.plasticity import PlasticityState
-
-        self._compiles[backend] = self._compiles.get(backend, 0) + 1
-        T, N = self.max_ticks, self.n_max
-        engine = self._engines[backend]
-
-        def per_slot(p, ext, pc, rew, until, fi, fm):
-            from repro.kernels.ops import EventFanIn
-
-            st = SNNState.zeros((), N)
-            pst = PlasticityState.zeros((), N)
-            nbrs = None if fi is None else EventFanIn(idx=fi, mask=fm)
-            out = engine.learning_rollout(
-                p, st, pst, ext, T, rewards=rew, plastic_c=pc,
-                learn_until=until, neighbors=nbrs)
-            if self.telemetry:
-                (_, _, w2), raster, telem = out
-                return raster, w2, telem           # (T, N), (N, N), scalars
-            (_, _, w2), raster = out
-            return raster, w2                      # (T, N), (N, N)
-
-        out = jax.vmap(per_slot)(params, ext_seq, plastic_c, rewards,
-                                 budget, fan_idx, fan_mask)
-        raster, w2 = out[:2]
-        # Per-request tick budgets: runtime masks, not shapes.
-        tmask = (jnp.arange(T)[None, :] < budget[:, None]).astype(raster.dtype)
-        counts = (raster * tmask[:, :, None]).sum(axis=1)   # (S, N) rate code
-        return (counts, w2, out[2]) if self.telemetry else (counts, w2)
+    # -- the compiled chunk program ----------------------------------------
 
     def _chunk_fn(self, backend, chunk, params, carry, ext, plastic_c,
                   rewards, offset, budget, learns, counts_acc,
@@ -667,20 +608,22 @@ class SNNServer:
         one trace serves every refill; only the chunk size and backend
         are static. The count mask compares the absolute tick index
         (``offset + arange``) against the budget, so partial counts
-        summed across chunks equal the wave path's one-shot masked sum
+        summed across chunks equal the request's one-shot masked sum
         exactly (small integers in f32 -- order-free).
 
         Each tick runs the tick body vmapped over the slots without the
         plasticity hook, then the hook slot by slot under a
         ``lax.cond``: only on slots that learn, and only while the carry's
         own tick counter (which persists across chunks) is below the
-        budget -- the wave path's ``learn_until=budget``. Under ``vmap``
+        budget -- a request run alone through
+        :meth:`~repro.core.engine.TickEngine.learning_rollout` with
+        ``learn_until=budget``, the per-request reference. Under ``vmap``
         a ``cond`` would lower to a select that runs both arms; out of
         it, a frozen slot or a tick past the budget costs the device no
         STDP pass, no ``learn_until`` select and no weight-delta norms.
-        On those ticks the wave path commits ``W`` unchanged and adds
-        exactly 0 to the norms, so counts, learned weights and telemetry
-        stay bit-identical to it."""
+        On those ticks the reference commits ``W`` unchanged and adds
+        exactly 0 to the norms, so counts, learned weights and the
+        weight-delta telemetry stay bit-identical to it."""
         from repro.core.engine import TickEngine
         from repro.kernels.ops import EventFanIn
 
@@ -719,7 +662,7 @@ class SNNServer:
                     # (``go[s]`` holds here): a cond's operands are
                     # materialized, so the sum reads w2 as stored. XLA may
                     # otherwise fuse a copy of w2's arithmetic into the
-                    # reduction, which rounds apart from the wave path's
+                    # reduction, which rounds apart from the reference's
                     # norms; an optimization barrier does not last until
                     # the CPU backend's fusion.
                     tel = jax.lax.cond(
@@ -743,77 +686,8 @@ class SNNServer:
         counts = (raster * tmask[:, :, None]).sum(axis=0)        # (S, N)
         return carry2, counts_acc + counts
 
-    # -- wave assembly (host side) ----------------------------------------
-
-    def _assemble(self, reqs: List[ServeRequest]):
-        S, T, N = self.slots, self.max_ticks, self.n_max
-        stack = lambda leaves: jax.tree.map(lambda *xs: jnp.stack(xs), *leaves)
-        params = stack([self.tenants[r.tenant].params for r in reqs])
-        plastic_c = jnp.stack(
-            [self.tenants[r.tenant].plastic_c for r in reqs])
-        ext = np.zeros((S, T, N), np.float32)
-        rew = np.zeros((S, T), np.float32)
-        budget = np.zeros((S,), np.int32)
-        for i, r in enumerate(reqs):
-            t = min(r.ext.shape[0], T)
-            ext[i, :t, : r.ext.shape[1]] = r.ext[:t]
-            if r.rewards is not None:
-                rew[i, : min(len(r.rewards), T)] = r.rewards[:T]
-            budget[i] = 0 if r.rid < 0 else min(r.n_ticks, T)
-        args = (params, jnp.asarray(ext), plastic_c, jnp.asarray(rew),
-                jnp.asarray(budget))
-        backends = {self.tenants[r.tenant].backend for r in reqs}
-        if backends != {"event"}:
-            return args + (None, None)
-        fan_idx = jnp.stack([self.tenants[r.tenant].fan_idx for r in reqs])
-        fan_mask = jnp.stack([self.tenants[r.tenant].fan_mask for r in reqs])
-        return args + (fan_idx, fan_mask)
-
-    def run_wave(self, reqs: List[ServeRequest]) -> None:
-        """One wave: S tenant register images in, S rate-decoded outputs
-        (and, for plastic tenants, learned weights written back).
-
-        A wave is backend-homogeneous (admission groups by tenant
-        backend), so each wave runs one of the server's resident
-        programs -- no per-slot branching inside the compiled tick."""
-        backends = {self.tenants[r.tenant].backend for r in reqs}
-        if len(backends) != 1:
-            raise ValueError(f"wave mixes backends {sorted(backends)}")
-        backend = backends.pop()
-        run = self._run_for(backend)
-        with span(f"snn/wave/{backend}", histogram=self._h_wave,
-                  backend=backend):
-            out = jax.block_until_ready(run(*self._assemble(reqs)))
-        self._c_waves.inc(backend=backend)
-        self._c_slot_ticks.inc(self.slots * self.max_ticks)
-        if self.telemetry:
-            counts, w2, telem = out
-            tel = jax.tree.map(np.asarray, telem)
-            self._c_overflow.inc(float(tel.overflow.sum()))
-            self._c_policy.inc(float(tel.policy_dense.sum()))
-            self._c_dw.inc(float(tel.dw_l1.sum()))
-        else:
-            counts, w2 = out
-            tel = None
-        now = time.time()
-        counts = np.asarray(counts)
-        for i, r in enumerate(reqs):
-            if r.rid < 0:
-                continue
-            t = self.tenants[r.tenant]
-            out = counts[i, t.n - t.n_out : t.n]
-            r.counts = out
-            r.pred = int(out.argmax())
-            r.t_first = r.t_done = now
-            if tel is not None:
-                self._observe_slot(t, tel, i)
-            if t.plastic:
-                # Register write-back: the tenant's next wave starts from
-                # the weights this wave learned (still fabric-shaped).
-                t.params = dataclasses.replace(t.params, w=w2[i])
-
     def _observe_slot(self, t: Tenant, tel, i: int) -> None:
-        """Fold slot ``i`` of a wave's telemetry into the tenant ledger."""
+        """Fold slot ``i`` of a chunk's telemetry into the tenant ledger."""
         o = self._tenant_obs.setdefault(t.name, {
             "requests": 0, "ticks": 0, "spikes": 0.0, "v_max": 0.0,
             "ref_sum": 0.0, "overflow_ticks": 0, "policy_dense_ticks": 0,
@@ -828,7 +702,7 @@ class SNNServer:
         o["dw_l1"] += float(tel.dw_l1[i])
 
     def tenant_report(self) -> Dict[str, Dict]:
-        """Per-tenant activity from accumulated wave telemetry.
+        """Per-tenant activity from accumulated slot telemetry.
 
         ``spike_rate`` is spikes per live-neuron-tick (padded fabric
         neurons carry an unreachable threshold, so every spike belongs
@@ -859,12 +733,11 @@ class SNNServer:
             }
         return rep
 
-    def _stats(self, *, mode: str, done: List[ServeRequest],
-               n_rejected: int, waves: int = 0, chunks: int = 0,
-               ticks: int = 0, slot_ticks: int = 0,
+    def _stats(self, *, done: List[ServeRequest], n_rejected: int,
+               chunks: int = 0, ticks: int = 0, slot_ticks: int = 0,
                wall_s: float = 0.0) -> Dict:
-        """ONE stats schema for the wave path, the continuous path and
-        the empty report -- identical key sets, no drift (pinned in
+        """ONE stats schema for a served call and the empty report --
+        identical key sets, no drift (pinned in
         tests/test_serve_continuous.py).
 
         ``slot_ticks_per_s`` is the raw rate (every tick the fabric ran,
@@ -877,12 +750,10 @@ class SNNServer:
         useful = sum(min(int(r.n_ticks), self.max_ticks) for r in done)
         total_spikes = float(sum(r.counts.sum() for r in done)) if done else 0.0
         return {
-            "mode": mode,
             "n_requests": len(done),
             "requests_served": len(done),
             "requests_rejected": n_rejected,
             "n_tenants": len({r.tenant for r in done}),
-            "waves": waves,
             "chunks": chunks,
             "ticks": ticks,
             "useful_slot_ticks": useful,
@@ -897,8 +768,8 @@ class SNNServer:
             "p99_ttft_s":
                 round(float(np.percentile(ttfts, 99)), 4) if done else 0.0,
             "compiles": self.compiles,
-            # One trace per resident program (per backend, plus per
-            # (backend, chunk) for the continuous step) is warmup;
+            # One trace per resident program (the chunk step per
+            # (backend, chunk), the refill per backend) is warmup;
             # anything past that is a retrace regression.
             "recompiles_after_warmup": sum(
                 max(0, c - 1) for c in self._compiles.values()),
@@ -911,13 +782,9 @@ class SNNServer:
             "results": [ServeResult.of(r) for r in done],
         }
 
-    def _empty_stats(self, rejected: int, mode: str = "wave") -> Dict:
-        """A well-formed zero report: nothing ran, nothing was served."""
-        return self._stats(mode=mode, done=[], n_rejected=rejected)
-
     def _reject_unknown(self, requests: List[ServeRequest]):
         """Split off requests naming an unregistered tenant (counted,
-        logged, never a KeyError mid-wave)."""
+        logged, never a KeyError mid-serve)."""
         rejected = [r for r in requests if r.tenant not in self.tenants]
         if rejected:
             self._c_rejected.inc(len(rejected))
@@ -926,79 +793,7 @@ class SNNServer:
                       tenants=sorted({r.tenant for r in rejected}))
         return [r for r in requests if r.tenant in self.tenants], rejected
 
-    def serve(self, requests: List[ServeRequest]) -> Dict:
-        """Wave admission over a request queue + the LM server's stats.
-
-        Admission first rejects requests naming an unregistered tenant
-        (counted, logged, never a KeyError mid-wave), then groups the
-        queue by tenant backend (waves are backend-homogeneous: a sparse
-        tenant rides the event program, a dense one the default program
-        -- each program compiled once, ever), then keeps at most ONE
-        request per *plastic* tenant in any wave: two slots learning
-        from the same pre-wave registers would race on the write-back
-        (last slot wins, first request's learning silently lost).
-        Deferred duplicates ride the next wave, which starts from the
-        weights this wave learned.
-
-        The returned per-call stats dict is a *view* over this call;
-        ``server.registry`` accumulates the same quantities cumulatively
-        across calls (Prometheus text via ``registry.to_prometheus()``).
-        An empty or fully-rejected queue returns the zero report with
-        ``requests_served: 0`` -- never a ``np.mean([])`` warning.
-        """
-        requests, rejected = self._reject_unknown(requests)
-        if not requests:
-            return self._empty_stats(len(rejected))
-        now = time.time()
-        for r in requests:
-            if not r.t_submit:   # TTFT from enqueue: keep caller's stamp
-                r.t_submit = now
-        done: List[ServeRequest] = []
-        waves = 0
-        backends_in_use = sorted(
-            {self.tenants[r.tenant].backend for r in requests})
-        for backend in backends_in_use:
-            queue = [r for r in requests
-                     if self.tenants[r.tenant].backend == backend]
-            while queue:
-                self._g_queue.set(len(queue))
-                wave, deferred, plastic_in_wave = [], [], set()
-                for r in queue:
-                    t = self.tenants[r.tenant]
-                    admit = len(wave) < self.slots and not (
-                        t.plastic and r.tenant in plastic_in_wave)
-                    if admit:
-                        wave.append(r)
-                        if t.plastic:
-                            plastic_in_wave.add(r.tenant)
-                    else:
-                        deferred.append(r)
-                queue = deferred
-                while len(wave) < self.slots:  # static batch: pad w/ dummy
-                    wave.append(ServeRequest(
-                        rid=-1, tenant=wave[0].tenant,
-                        ext=np.zeros((1, 1), np.float32), n_ticks=0))
-                self.run_wave(wave)
-                done.extend(r for r in wave if r.rid >= 0)
-                waves += 1
-        self._g_queue.set(0)
-        t0 = min(r.t_submit for r in done)
-        t1 = max(r.t_done for r in done)
-        stats = self._stats(
-            mode="wave", done=done, n_rejected=len(rejected), waves=waves,
-            ticks=waves * self.max_ticks,
-            slot_ticks=waves * self.max_ticks * self.slots,
-            wall_s=t1 - t0)
-        self._c_requests.inc(len(done))
-        self._c_spikes.inc(stats["spikes_out"])
-        self._c_useful_ticks.inc(stats["useful_slot_ticks"])
-        self._g_goodput.set(stats["slot_ticks_per_s"])
-        self._g_useful_goodput.set(stats["goodput_slot_ticks_per_s"])
-        for r in done:
-            self._h_ttft.observe(r.t_first - r.t_submit)
-        return stats
-
-    # -- continuous admission (per-slot refill, not per-wave) --------------
+    # -- continuous admission (per-slot refill) ----------------------------
 
     def _fresh_slot_carry(self, tenant: Tenant):
         """A fresh single-slot :class:`~repro.core.engine.TickCarry` for
@@ -1052,8 +847,8 @@ class SNNServer:
                          tenants: Dict[str, Tenant]):
         """Pop the first FIFO request whose tenant isn't a currently
         resident *plastic* tenant (two slots learning from the same
-        pre-admission registers would race the write-back -- the wave
-        path's one-plastic-request-per-wave rule, continuized)."""
+        pre-admission registers would race the write-back; deferred,
+        the request starts from the weights its predecessor learned)."""
         for idx, r in enumerate(pending):
             t = tenants[r.tenant]
             if t.plastic and r.tenant in busy_plastic:
@@ -1085,11 +880,9 @@ class SNNServer:
         feeder: Optional[Callable[[], Optional[ServeRequest]]] = None,
         on_complete: Optional[Callable[[ServeRequest], None]] = None,
     ) -> Dict:
-        """Per-slot continuous admission: the tentpole replacement for
-        wave admission.
+        """Serve a request queue by per-slot continuous admission.
 
-        Instead of draining a whole wave before anything new admits, the
-        fabric runs in chunks of ``chunk_ticks`` ticks; after each chunk,
+        The fabric runs in chunks of ``chunk_ticks`` ticks; after each chunk,
         slots whose request exhausted its tick budget *retire* (decode,
         write back learned weights, complete) and are *refilled* from
         the queue -- without recompiling: the chunked step is one jitted
@@ -1110,10 +903,13 @@ class SNNServer:
             each request as it completes -- the async front-end resolves
             per-request futures here, long before the batch returns.
 
-        Returns the same stats schema as :meth:`serve`, with
-        ``mode="continuous"`` and chunk/goodput accounting filled in.
-        Per-tenant outputs are bit-exact vs the wave path (oracle test:
-        tests/test_serve_continuous.py).
+        Requests naming an unregistered tenant are rejected (counted,
+        logged); each backend's queue runs on its own resident program.
+        Returns the per-call stats (:meth:`_stats`); ``server.registry``
+        accumulates the same quantities across calls. Each request's
+        counts and prediction, and each plastic tenant's learned
+        weights, are bit-exact with the request run alone on the core
+        engine (the per-request reference in tests/serve_reference.py).
         """
         chunk = int(self.chunk_ticks if chunk_ticks is None else chunk_ticks)
         if not (1 <= chunk <= self.max_ticks):
@@ -1161,13 +957,13 @@ class SNNServer:
         self._g_queue.set(0)
         self._g_busy.set(0)
         if not done:
-            return self._empty_stats(len(rejected), mode="continuous")
+            return self._stats(done=[], n_rejected=len(rejected))
         t0 = min(r.t_submit for r in done)
         t1 = max(r.t_done for r in done)
         stats = self._stats(
-            mode="continuous", done=done, n_rejected=len(rejected),
-            chunks=chunks, ticks=chunks * chunk,
-            slot_ticks=chunks * chunk * self.slots, wall_s=t1 - t0)
+            done=done, n_rejected=len(rejected), chunks=chunks,
+            ticks=chunks * chunk, slot_ticks=chunks * chunk * self.slots,
+            wall_s=t1 - t0)
         self._c_spikes.inc(stats["spikes_out"])
         self._g_goodput.set(stats["slot_ticks_per_s"])
         self._g_useful_goodput.set(stats["goodput_slot_ticks_per_s"])
@@ -1223,8 +1019,8 @@ class SNNServer:
                 fresh = self._fresh_slot_carry(t)
                 if params_s is None:
                     # First fill seeds EVERY slot with this tenant's image;
-                    # idle slots ride along at budget 0 (masked to nothing),
-                    # exactly like the wave path's dummy padding.
+                    # idle slots ride along at budget 0 (masked to nothing,
+                    # never learning).
                     bcast = lambda x: jax.tree.map(
                         lambda a: jnp.broadcast_to(a, (S,) + a.shape), x)
                     params_s = bcast(t.params)
@@ -1270,8 +1066,8 @@ class SNNServer:
                     self._c_policy.inc(float(tel.policy_dense[i]))
                     self._c_dw.inc(float(tel.dw_l1[i]))
                 if t.plastic:
-                    # Register write-back, same as the wave path: the
-                    # tenant's next request starts from what this one learned.
+                    # Register write-back: the tenant's next request
+                    # starts from what this one learned.
                     t.params = dataclasses.replace(t.params, w=carry_s.w[i])
                     busy_plastic.discard(t.name)
                 slot_req[i] = slot_tenant[i] = None
@@ -1441,17 +1237,14 @@ def serve_snn_main(cfg, args) -> Dict:
           f"tenants, {args.slots} slots, {args.requests} requests")
     reqs = make_demo_requests(server, names, max(args.requests, len(names)))
     with profile(getattr(args, "profile", None)):
-        if getattr(args, "continuous", False):
-            stats = server.serve_continuous(reqs)
-        else:
-            stats = server.serve(reqs)
+        stats = server.serve_continuous(reqs)
     for k, v in stats.items():
         if k == "results":
             continue
         print(f"{k}: {v}")
     report = server.tenant_report()
     if report:
-        print("\nper-tenant activity (wave telemetry):")
+        print("\nper-tenant activity (slot telemetry):")
         for name, row in report.items():
             print(f"  {name}: " + ", ".join(
                 f"{k}={v}" for k, v in row.items()))
@@ -1618,9 +1411,6 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=64)
-    ap.add_argument("--continuous", action="store_true",
-                    help="use per-slot continuous admission instead of "
-                         "synchronous waves (SNN server only)")
     ap.add_argument("--profile", metavar="DIR", default=None,
                     help="capture a jax.profiler trace of the serve run "
                          "into DIR (view with TensorBoard/Perfetto)")

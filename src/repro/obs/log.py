@@ -1,6 +1,6 @@
 """Structured event logging: JSON-lines records, buffered + streamable.
 
-The serving and benchmark paths emit *events* (wave admitted, tenant
+The serving and benchmark paths emit *events* (requests rejected, tenant
 registered, profile captured) rather than printf strings, so a consumer
 -- the regression gate, a notebook, `jq` -- can filter on fields instead
 of parsing prose.

@@ -8,10 +8,10 @@ the exposition format is a page of text protocol:
 
 Three instrument kinds, all label-aware:
 
-* :class:`Counter` -- monotonically increasing (requests, spikes, waves).
+* :class:`Counter` -- monotonically increasing (requests, spikes, chunks).
 * :class:`Gauge` -- last-write-wins (queue depth, resident tenants).
 * :class:`Histogram` -- fixed buckets, cumulative counts + sum/count
-  (TTFT, wave wall time).
+  (TTFT, queue wait).
 
 One :class:`MetricsRegistry` owns the instruments and renders both a
 Prometheus text exposition (:meth:`MetricsRegistry.to_prometheus`) and a

@@ -9,7 +9,7 @@ Two different tools for two different views of the same program:
   scopes do not perturb the lowered program).
 
 * :func:`span` -- ``jax.profiler.TraceAnnotation``: marks *host wall
-  time* regions (wave admission, encode/decode) so a captured profiler
+  time* regions (slot refill, encode/decode) so a captured profiler
   trace shows where serving time actually went. Optionally observes the
   elapsed seconds into a :class:`~repro.obs.metrics.Histogram`.
 

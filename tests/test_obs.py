@@ -18,7 +18,7 @@ Four pins (DESIGN.md §11):
 
 * **Host-side instruments** -- the dependency-free registry renders a
   valid Prometheus 0.0.4 text exposition and JSON dump; the SNN server
-  reports requests/waves/TTFT/tenant activity through it, and its
+  reports requests/chunks/TTFT/tenant activity through it, and its
   empty-queue / all-rejected paths return well-formed zero reports.
 """
 import io
@@ -467,24 +467,25 @@ class TestServeObservability:
         server = self._server()
         names = make_demo_tenants(server, 4, seed=2)
         reqs = make_demo_requests(server, names, 8, seed=3)
-        stats = server.serve(reqs)
+        stats = server.serve_continuous(reqs)
         reg = server.registry
         assert reg.get("snn_requests_total").value() == stats["requests_served"] == 8
         assert reg.get("snn_ttft_seconds").count() == 8
         assert reg.get("snn_queue_depth").value() == 0.0
         assert reg.get("snn_slot_ticks_total").value() == \
-            stats["waves"] * server.slots * server.max_ticks
+            stats["chunks"] * server.slots * server.chunk_ticks
         text = reg.to_prometheus()
         assert "# TYPE snn_requests_total counter" in text
         assert "# TYPE snn_ttft_seconds histogram" in text
-        assert "snn_waves_total{backend=" in text
+        assert "snn_chunks_total{backend=" in text
 
     def test_tenant_report(self):
         from repro.launch.serve import make_demo_requests, make_demo_tenants
 
         server = self._server()
         names = make_demo_tenants(server, 4, seed=2)
-        stats = server.serve(make_demo_requests(server, names, 8, seed=3))
+        stats = server.serve_continuous(
+            make_demo_requests(server, names, 8, seed=3))
         report = server.tenant_report()
         assert set(report) == set(names)
         assert sum(r["requests"] for r in report.values()) \
@@ -500,11 +501,11 @@ class TestServeObservability:
 
     def test_empty_queue_zero_report(self):
         server = self._server()
-        stats = server.serve([])
+        stats = server.serve_continuous([])
         assert stats["n_requests"] == 0
         assert stats["requests_served"] == 0
         assert stats["requests_rejected"] == 0
-        assert stats["waves"] == 0
+        assert stats["chunks"] == 0
         assert stats["mean_ttft_s"] == 0.0
 
     def test_unknown_tenant_rejected_not_keyerror(self):
@@ -513,7 +514,7 @@ class TestServeObservability:
         server = self._server()
         bad = ServeRequest(rid=0, tenant="ghost",
                          ext=np.zeros((4, 4), np.float32), n_ticks=4)
-        stats = server.serve([bad])
+        stats = server.serve_continuous([bad])
         assert stats["requests_served"] == 0
         assert stats["requests_rejected"] == 1
         assert server.registry.get("snn_requests_rejected_total").value() == 1
@@ -523,7 +524,8 @@ class TestServeObservability:
 
         server = self._server(telemetry=False)
         names = make_demo_tenants(server, 4, seed=2)
-        stats = server.serve(make_demo_requests(server, names, 4, seed=3))
+        stats = server.serve_continuous(
+            make_demo_requests(server, names, 4, seed=3))
         assert stats["requests_served"] == 4
         assert server.tenant_report() == {}
 

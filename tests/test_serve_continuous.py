@@ -1,10 +1,12 @@
-"""Continuous admission: per-slot refill equals the wave path exactly.
+"""Continuous admission: per-slot refill equals each request run alone.
 
-Pins the tentpole contract: chunked per-slot scheduling returns the
-same per-request counts, predictions, and learned weights as wave
-admission, bit for bit -- while never retracing across slot refills,
-mixing dense and event tenants, and handling the admission edges
-(zero-tick budgets, unknown tenants, feeder-streamed late arrivals).
+Pins the serving contract: chunked per-slot scheduling returns the
+same per-request counts, predictions, and learned weights as the
+per-request reference (``serve_reference``: each request alone on the
+unbatched core engine, in submission order), bit for bit -- while
+never retracing across slot refills, mixing dense and event tenants,
+and handling the admission edges (zero-tick budgets, unknown tenants,
+feeder-streamed late arrivals).
 """
 import functools
 import re
@@ -19,13 +21,15 @@ from repro.launch.serve import (
     ServeRequest, ServeResult, SNNServer, make_demo_requests,
     make_demo_tenants,
 )
+from serve_reference import serve_reference
 
 jax.config.update("jax_platform_name", "cpu")
 
 
 def _twin_servers(**kw):
     """Two identically-built servers (tenants, seeds, everything) so the
-    wave and continuous paths start from the same learned state."""
+    reference and the continuous path start from the same learned
+    state."""
     kw.setdefault("n_max", 24)
     kw.setdefault("slots", 4)
     kw.setdefault("max_ticks", 12)
@@ -36,44 +40,53 @@ def _twin_servers(**kw):
     return a, b, names
 
 
+def _assert_same(ref_server, reqs_ref, server, reqs, msg=""):
+    """Bitwise: every request's counts and prediction, every tenant's
+    weights (the learned ones included)."""
+    for a, b in zip(reqs_ref, reqs):
+        assert a.pred == b.pred, msg
+        np.testing.assert_array_equal(a.counts, b.counts, err_msg=msg)
+    for n in ref_server.tenants:
+        np.testing.assert_array_equal(
+            np.asarray(ref_server.tenants[n].params.w),
+            np.asarray(server.tenants[n].params.w), err_msg=msg)
+
+
 class TestWaveOracle:
+    """The per-request reference is the oracle of the served path."""
+
     def test_counts_preds_weights_bit_exact_vs_wave(self):
-        sw, sc, names = _twin_servers()
-        reqs_w = make_demo_requests(sw, names, 16, seed=1)
+        sr, sc, names = _twin_servers()
+        reqs_r = make_demo_requests(sr, names, 16, seed=1)
         reqs_c = make_demo_requests(sc, names, 16, seed=1)
-        sw.serve(reqs_w)
+        serve_reference(sr, reqs_r)
         sc.serve_continuous(reqs_c)
-        for a, b in zip(reqs_w, reqs_c):
-            assert a.pred == b.pred
-            np.testing.assert_array_equal(a.counts, b.counts)
         # Plastic write-back: the learned registers match too.
-        for n in names:
-            np.testing.assert_array_equal(
-                np.asarray(sw.tenants[n].params.w),
-                np.asarray(sc.tenants[n].params.w))
+        _assert_same(sr, reqs_r, sc, reqs_c)
 
     def test_exact_across_chunk_sizes(self):
-        sw, _, names = _twin_servers()
-        reqs_w = make_demo_requests(sw, names, 8, seed=3)
-        sw.serve(reqs_w)
+        sr, _, names = _twin_servers()
+        reqs_r = make_demo_requests(sr, names, 8, seed=3)
+        serve_reference(sr, reqs_r)
         for chunk in (1, 5, 12):
             sc = SNNServer(n_max=24, slots=4, max_ticks=12,
                            event_density=0.2)
             make_demo_tenants(sc, 8, seed=0)
             reqs_c = make_demo_requests(sc, names, 8, seed=3)
             sc.serve_continuous(reqs_c, chunk_ticks=chunk)
-            for a, b in zip(reqs_w, reqs_c):
-                assert a.pred == b.pred, f"chunk_ticks={chunk}"
-                np.testing.assert_array_equal(a.counts, b.counts)
+            _assert_same(sr, reqs_r, sc, reqs_c, f"chunk_ticks={chunk}")
 
     def test_mixed_dense_and_event_tenants(self):
-        _, sc, names = _twin_servers()
+        sr, sc, names = _twin_servers()
         backends = {sc.tenants[n].backend for n in names}
         assert backends == {"jnp", "event"}
-        reqs = make_demo_requests(sc, names, 12, seed=2)
-        stats = sc.serve_continuous(reqs)
+        reqs_r = make_demo_requests(sr, names, 12, seed=2)
+        reqs_c = make_demo_requests(sc, names, 12, seed=2)
+        serve_reference(sr, reqs_r)
+        stats = sc.serve_continuous(reqs_c)
         assert stats["requests_served"] == 12
         assert set(stats["backends"]) == {"jnp", "event"}
+        _assert_same(sr, reqs_r, sc, reqs_c)
 
 
 def _requests(server, spec, *, seed):
@@ -110,31 +123,33 @@ _LEARNING_CASES = {
 }
 
 
+def _assert_same_dw(dw_ref, server):
+    """The served weight-delta telemetry equals the reference's, per
+    tenant, as summed and as reported."""
+    rep = server.tenant_report()
+    for n in rep:
+        assert server._tenant_obs[n]["dw_l1"] == dw_ref[n]
+        assert rep[n]["dw_l1"] == round(dw_ref[n], 3)
+
+
 class TestLearningOnlyWhereItLearns:
     """The chunk program runs the plasticity hook only on slot-ticks that
-    learn; the wave path runs it (gated by ``learn_until``) on every
-    slot-tick, and is the oracle."""
+    learn; the reference runs each request alone through
+    ``learning_rollout`` with ``learn_until=budget``, and is the
+    oracle."""
 
     @pytest.mark.parametrize("case", sorted(_LEARNING_CASES))
     def test_bit_exact_vs_wave(self, case):
-        sw, sc, _ = _twin_servers()
+        sr, sc, _ = _twin_servers()
         spec = _LEARNING_CASES[case]
-        reqs_w = _requests(sw, spec, seed=7)
+        reqs_r = _requests(sr, spec, seed=7)
         reqs_c = _requests(sc, spec, seed=7)
-        sw.serve(reqs_w)
+        dw_ref = serve_reference(sr, reqs_r)
         sc.serve_continuous(reqs_c, chunk_ticks=4)
-        for a, b in zip(reqs_w, reqs_c):
-            assert a.pred == b.pred
-            np.testing.assert_array_equal(a.counts, b.counts)
-        for n in sw.tenants:
-            np.testing.assert_array_equal(
-                np.asarray(sw.tenants[n].params.w),
-                np.asarray(sc.tenants[n].params.w))
-        rep_w, rep_c = sw.tenant_report(), sc.tenant_report()
-        assert set(rep_w) == set(rep_c)
-        for n in rep_w:
-            assert rep_w[n]["dw_l1"] == rep_c[n]["dw_l1"]
-            assert sw._tenant_obs[n]["dw_l1"] == sc._tenant_obs[n]["dw_l1"]
+        _assert_same(sr, reqs_r, sc, reqs_c)
+        rep_c = sc.tenant_report()
+        assert set(rep_c) == set(dw_ref) == {n for n, _ in spec}
+        _assert_same_dw(dw_ref, sc)
         plastic_dw = sum(rep_c[n]["dw_l1"] for n in rep_c
                          if sc.tenants[n].plastic)
         assert (plastic_dw > 0) == any(n == "dense-7" for n, _ in spec)
@@ -300,24 +315,18 @@ class TestDonationSafe:
 
     @pytest.mark.parametrize("chunk", [1, 4])
     def test_bit_exact_vs_wave_across_refills_and_calls(self, chunk):
-        sw, sc, _ = _twin_servers()
+        sr, sc, _ = _twin_servers()
+        dw_ref = {}
         for seed, spec in enumerate(self.BATCHES):
-            reqs_w = _requests(sw, spec, seed=seed)
+            reqs_r = _requests(sr, spec, seed=seed)
             reqs_c = _requests(sc, spec, seed=seed)
-            sw.serve(reqs_w)
+            for n, dw in serve_reference(sr, reqs_r).items():
+                dw_ref[n] = dw_ref.get(n, 0.0) + dw
             _serve_erroring_on_warnings(sc, reqs_c, chunk_ticks=chunk)
-            for a, b in zip(reqs_w, reqs_c):
-                assert a.pred == b.pred
-                np.testing.assert_array_equal(a.counts, b.counts)
-            for n in sw.tenants:
-                np.testing.assert_array_equal(
-                    np.asarray(sw.tenants[n].params.w),
-                    np.asarray(sc.tenants[n].params.w))
-            # The continuous path observes only slots that ran a tick, so
-            # a tenant seen only at budget 0 is in the wave report alone.
-            rep_w, rep_c = sw.tenant_report(), sc.tenant_report()
-            for n in rep_c:
-                assert rep_w[n]["dw_l1"] == rep_c[n]["dw_l1"]
+            _assert_same(sr, reqs_r, sc, reqs_c)
+            # The served path observes only slots that ran a tick, so a
+            # tenant seen only at budget 0 is in the reference's alone.
+            _assert_same_dw(dw_ref, sc)
         assert sc.tenant_report()["dense-7"]["dw_l1"] > 0
 
 
@@ -365,15 +374,19 @@ class TestAdmissionEdges:
 
 class TestStatsSchema:
     def test_same_keys_wave_continuous_and_empty(self):
-        sw, sc, names = _twin_servers()
-        wave = sw.serve(make_demo_requests(sw, names, 4, seed=1))
+        """A served call, an empty queue and a fully rejected one report
+        the same keys."""
+        _, sc, names = _twin_servers()
         cont = sc.serve_continuous(make_demo_requests(sc, names, 4, seed=1))
         empty = sc.serve_continuous([])
-        assert set(wave) == set(cont) == set(empty)
-        assert wave["mode"] == "wave"
-        assert cont["mode"] == "continuous"
+        ghost = ServeRequest(rid=0, tenant="ghost",
+                             ext=np.zeros((2, 4), np.float32), n_ticks=2)
+        rejected = sc.serve_continuous([ghost])
+        assert set(cont) == set(empty) == set(rejected)
+        assert cont["requests_served"] == 4 and cont["chunks"] > 0
         assert empty["requests_served"] == 0
         assert empty["p99_ttft_s"] == 0.0
+        assert rejected["requests_rejected"] == 1
 
     def test_ttft_measured_from_enqueue_not_wave_start(self):
         _, sc, names = _twin_servers()
@@ -382,7 +395,7 @@ class TestStatsSchema:
         for r in reqs:
             r.t_submit = t_early
         stats = sc.serve_continuous(reqs)
-        # If TTFT were re-stamped at wave/chunk start these would be
+        # If TTFT were re-stamped at slot-fill start these would be
         # sub-second; from the caller's enqueue they are epoch-sized.
         assert stats["mean_ttft_s"] > 1e6
 

@@ -1,11 +1,12 @@
-"""Multi-tenant SNN serving: one compiled tick program, many networks.
+"""Multi-tenant SNN serving: one compiled chunk program, many networks.
 
 Pins the acceptance criteria: >= 8 heterogeneous tenants (different C
 topologies and LIF registers, incl. a plastic one) through ONE jitted
-program with zero recompiles across tenant swaps; frozen tenants come
-back bit-identical from the shared learning datapath; the served
-datapath equals the core engine run tenant-by-tenant; per-request tick
-budgets mask, never retrace.
+chunk program (plus its slot-refill program) per backend, with zero
+recompiles across tenant swaps; frozen tenants come back bit-identical
+from the shared learning datapath; the served datapath equals the core
+engine run tenant-by-tenant; per-request tick budgets mask, never
+retrace.
 """
 import jax
 import jax.numpy as jnp
@@ -43,6 +44,10 @@ def _layered_bank(n_in, n_out, *, w=120, th=80, seed=0):
     return bank
 
 
+# One backend's resident programs: the chunk step and the slot refill.
+PROGRAMS_PER_BACKEND = 2
+
+
 def _drive(t, n_in, *, mag=200.0, p=0.5, seed=0):
     rng = np.random.default_rng(seed)
     return ((rng.random((t, n_in)) < p) * mag).astype(np.float32)
@@ -57,14 +62,16 @@ class TestOneProgramManyTenants:
         cs = [np.asarray(server.tenants[n].params.c) for n in names]
         assert len({c.tobytes() for c in cs}) == 8
         reqs = make_demo_requests(server, names, 16, seed=2)
-        stats = server.serve(reqs)
+        stats = server.serve_continuous(reqs)
         assert stats["n_requests"] == 16
         assert stats["n_tenants"] == 8
-        assert stats["compiles"] == 1, "slot/tenant churn must not retrace"
+        assert stats["compiles"] == PROGRAMS_PER_BACKEND, \
+            "slot/tenant churn must not retrace"
         assert stats["recompiles_after_warmup"] == 0
         # serving again (new tenants swapped through the same slots) stays warm
-        stats2 = server.serve(make_demo_requests(server, names, 8, seed=3))
-        assert stats2["compiles"] == 1
+        stats2 = server.serve_continuous(
+            make_demo_requests(server, names, 8, seed=3))
+        assert stats2["compiles"] == PROGRAMS_PER_BACKEND
         assert stats2["recompiles_after_warmup"] == 0
 
     def test_served_wave_matches_core_engine_per_tenant(self):
@@ -72,7 +79,7 @@ class TestOneProgramManyTenants:
         server = _server(slots=4, max_ticks=8)
         names = make_demo_tenants(server, 4, seed=5)
         reqs = make_demo_requests(server, names, 4, seed=6)
-        stats = server.serve(reqs)
+        stats = server.serve_continuous(reqs)
         for r in reqs:
             t = server.tenants[r.tenant]
             ext = np.zeros((server.max_ticks, server.n_max), np.float32)
@@ -97,7 +104,7 @@ class TestOneProgramManyTenants:
         server.add_tenant("biased", bank, n_in=n_in, n_out=n_out)
         req = ServeRequest(rid=0, tenant="biased",
                          ext=_drive(8, n_in, seed=1), n_ticks=8)
-        server.serve([req])
+        server.serve_continuous([req])
         assert req.pred == 1
         assert req.counts[1] > 0
 
@@ -108,8 +115,8 @@ class TestOneProgramManyTenants:
         ext = _drive(10, 4, seed=4)
         full = ServeRequest(rid=0, tenant="t", ext=ext, n_ticks=10)
         short = ServeRequest(rid=1, tenant="t", ext=ext, n_ticks=3)
-        server.serve([full, short])
-        assert server.compiles == 1
+        server.serve_continuous([full, short])
+        assert server.compiles == PROGRAMS_PER_BACKEND
         assert short.counts.sum() <= full.counts.sum()
         # budget-3 counts == the first 3 ticks of the full raster
         t = server.tenants["t"]
@@ -164,17 +171,17 @@ class TestEventTenancy:
             reqs.append(ServeRequest(rid=i, tenant=name,
                                    ext=_drive(6, t.n_in, seed=30 + i),
                                    n_ticks=6))
-        stats = server.serve(reqs)
+        stats = server.serve_continuous(reqs)
         assert stats["n_requests"] == 5
         assert stats["backends"] == {"event": 3, "jnp": 2}
-        assert stats["compiles"] == 2          # one per resident program
+        assert stats["compiles"] == 2 * PROGRAMS_PER_BACKEND
         assert stats["recompiles_after_warmup"] == 0
-        # a second mixed queue stays warm on both programs
-        stats2 = server.serve([ServeRequest(
+        # a second mixed queue stays warm on both backends' programs
+        stats2 = server.serve_continuous([ServeRequest(
             rid=9, tenant=name, ext=_drive(5, server.tenants[name].n_in,
                                            seed=40), n_ticks=5)
             for name in ("s1", "d0")])
-        assert stats2["compiles"] == 2
+        assert stats2["compiles"] == 2 * PROGRAMS_PER_BACKEND
         assert stats2["recompiles_after_warmup"] == 0
 
     def test_event_wave_matches_core_engine_rollout(self):
@@ -185,7 +192,7 @@ class TestEventTenancy:
                           n_in=N_MAX, n_out=N_MAX)
         req = ServeRequest(rid=0, tenant="s", ext=_drive(8, N_MAX, seed=27),
                          n_ticks=8)
-        server.serve([req])
+        server.serve_continuous([req])
         t = server.tenants["s"]
         ext = np.zeros((8, N_MAX), np.float32)
         ext[: req.ext.shape[0]] = req.ext
@@ -224,8 +231,8 @@ class TestPlasticTenancy:
         np.testing.assert_array_equal(w_frozen0, w_plastic0)
 
         ext = _drive(10, 4, p=0.7, seed=8)
-        for wave in range(3):
-            server.serve([
+        for _ in range(3):
+            server.serve_continuous([
                 ServeRequest(rid=0, tenant="frozen", ext=ext, n_ticks=10),
                 ServeRequest(rid=1, tenant="plastic", ext=ext, n_ticks=10),
             ])
@@ -233,11 +240,11 @@ class TestPlasticTenancy:
         w_plastic1 = np.asarray(server.tenants["plastic"].params.w)
         # shared learning datapath, exact no-op for the frozen tenant
         np.testing.assert_array_equal(w_frozen0, w_frozen1)
-        # the plastic tenant's registers moved (write-back across waves)
+        # the plastic tenant's registers moved (write-back across requests)
         assert not np.array_equal(w_plastic0, w_plastic1)
         # and stayed in the u8 register domain (serializable to the bank)
         assert w_plastic1.min() >= 0.0 and w_plastic1.max() <= 255.0
-        assert server.compiles == 1
+        assert server.compiles == PROGRAMS_PER_BACKEND
 
     def test_same_plastic_tenant_twice_equals_sequential(self):
         """Two requests for one plastic tenant must not race on write-back:
@@ -251,12 +258,14 @@ class TestPlasticTenancy:
 
         e1, e2 = _drive(8, 4, p=0.7, seed=13), _drive(8, 4, p=0.7, seed=14)
         together = build()
-        together.serve([
+        together.serve_continuous([
             ServeRequest(rid=0, tenant="p", ext=e1, n_ticks=8),
             ServeRequest(rid=1, tenant="p", ext=e2, n_ticks=8)])
         sequential = build()
-        sequential.serve([ServeRequest(rid=0, tenant="p", ext=e1, n_ticks=8)])
-        sequential.serve([ServeRequest(rid=1, tenant="p", ext=e2, n_ticks=8)])
+        sequential.serve_continuous(
+            [ServeRequest(rid=0, tenant="p", ext=e1, n_ticks=8)])
+        sequential.serve_continuous(
+            [ServeRequest(rid=1, tenant="p", ext=e2, n_ticks=8)])
         np.testing.assert_array_equal(
             np.asarray(together.tenants["p"].params.w),
             np.asarray(sequential.tenants["p"].params.w))
@@ -270,29 +279,30 @@ class TestPlasticTenancy:
             server = _server(slots=2, max_ticks=max_ticks)
             server.add_tenant("p", _layered_bank(4, 4, seed=16), n_in=4,
                               n_out=4, plastic=True)
-            server.serve([ServeRequest(rid=0, tenant="p", ext=ext, n_ticks=6)])
+            server.serve_continuous(
+                [ServeRequest(rid=0, tenant="p", ext=ext, n_ticks=6)])
             return np.asarray(server.tenants["p"].params.w)
 
         np.testing.assert_array_equal(learned_w(6), learned_w(12))
 
     def test_serve_empty_queue(self):
         server = _server()
-        stats = server.serve([])
-        assert stats["n_requests"] == 0 and stats["waves"] == 0
+        stats = server.serve_continuous([])
+        assert stats["n_requests"] == 0 and stats["chunks"] == 0
         assert stats["requests_served"] == 0
         assert stats["mean_ttft_s"] == 0.0  # never np.mean([])
 
     def test_serve_fully_rejected_queue(self):
         """Every request names an unknown tenant: zero report, counted
-        rejections, no KeyError mid-wave."""
+        rejections, no KeyError mid-serve."""
         server = _server()
         bad = [ServeRequest(rid=i, tenant=f"ghost-{i}",
                           ext=np.zeros((4, 4), np.float32), n_ticks=4)
                for i in range(3)]
-        stats = server.serve(bad)
+        stats = server.serve_continuous(bad)
         assert stats["requests_served"] == 0
         assert stats["requests_rejected"] == 3
-        assert stats["waves"] == 0 and stats["mean_ttft_s"] == 0.0
+        assert stats["chunks"] == 0 and stats["mean_ttft_s"] == 0.0
 
     def test_rectangular_w_in_pads(self):
         import dataclasses as dc
@@ -314,7 +324,8 @@ class TestPlasticTenancy:
         w0 = np.asarray(t.params.w).copy()
         c = np.asarray(t.params.c)
         ext = _drive(10, 4, p=0.8, seed=10)
-        server.serve([ServeRequest(rid=0, tenant="p", ext=ext, n_ticks=10)])
+        server.serve_continuous(
+            [ServeRequest(rid=0, tenant="p", ext=ext, n_ticks=10)])
         w1 = np.asarray(server.tenants["p"].params.w)
         np.testing.assert_array_equal(w0[c == 0], w1[c == 0])
 
