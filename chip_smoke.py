@@ -8,10 +8,14 @@ One process, phases in order, one JSON line each on stdout:
   tenants of mixed topology, one of them plastic, 4 slots) served by
   :meth:`SNNServer.serve_continuous`, built as the serve CLI builds it.
   The line gives the bytes each continuous program (slot refill, chunk)
-  updates in place, ``alias_bytes``; both must be non-zero.
+  updates in place, ``alias_bytes``; both must be non-zero.  It also gives
+  ``slot_slice_bytes``, the bytes of whole ``(n, n)`` matrices the chunk
+  program slices out of its slot stacks outside the learning ``cond``;
+  on a TPU it must be 0.
 * **B -- the full-width dense fabric.**  ``--arch snn-fused`` (n_max=4096,
   ``pallas_fused``) served the same way; sparse tenants ride the second,
-  event, program.
+  event, program.  ``slot_slice_bytes`` 0 here shows the whole-tick
+  kernel reading each slot's ``W`` and ``C`` in place, on its slot grid.
 * **C -- the event kernel.**  The ``snn-event`` fabric (n=4096, density
   0.05, input rate 0.05, batch 8) through
   :func:`repro.core.network.rollout` with ``backend="event"``: once with
@@ -78,7 +82,7 @@ from repro.core.lif import LIFParams  # noqa: E402
 from repro.core.network import rollout  # noqa: E402
 from repro.core.network_types import SNNParams, SNNState  # noqa: E402
 from repro.launch import serve  # noqa: E402
-from repro.launch.hlo_cost import mosaic_kernels  # noqa: E402
+from repro.launch.hlo_cost import dynamic_slices, mosaic_kernels  # noqa: E402
 from repro.plasticity import PlasticityState  # noqa: E402
 from repro.util.env import enable_compilation_cache  # noqa: E402
 
@@ -268,12 +272,22 @@ def phase_serve(tag: str, arch: str, smoke: bool, clock: CompileClock,
     _check(all(line["alias_bytes"].values()),
            f"a continuous program copies its stacked inputs: "
            f"{line['alias_bytes']}")
+    # Whole (n, n) matrices sliced out of the slot stacks outside the
+    # learning cond: 0 when every kernel reads its slot's operands in place
+    # (interpreted kernels slice their blocks, so off the TPU it is not).
+    text = server.chunk_program_text(default_backend)
+    n = server.n_max
+    line["slot_slice_bytes"] = sum(
+        b for b, op_name in dynamic_slices(text, (n, n))
+        if "/cond/" not in op_name)
+    _check(line["slot_slice_bytes"] == 0 or not on_tpu,
+           f"the chunk program slices {line['slot_slice_bytes']} bytes of "
+           f"whole slot matrices outside the learning cond")
     line["parity"] = _served_parity(server, reqs, w0)
     _check(backends.get(default_backend, 0) > 0,
            f"no request rode the {default_backend!r} program")
     if kernel:
-        line["kernels"] = mosaic_kernels(
-            server.chunk_program_text(default_backend))
+        line["kernels"] = mosaic_kernels(text)
         _check(kernel in line["kernels"] or not on_tpu,
                f"{default_backend} chunk program does not call {kernel}: "
                f"{line['kernels']}")

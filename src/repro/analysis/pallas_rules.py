@@ -86,7 +86,7 @@ def _oob_for_operand(op: Operand, points, prefetch) -> Optional[Any]:
         if len(idx) != len(op.block_shape):
             return point, idx
         for i, b, extent in zip(idx, op.block_shape, op.shape):
-            if i < 0 or (i + 1) * b > extent:
+            if i < 0 or (i + 1) * (b or 1) > extent:
                 return point, idx
     return None
 

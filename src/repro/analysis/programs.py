@@ -353,6 +353,16 @@ def kernel_launches() -> Tuple[Tuple[str, KernelLaunch], ...]:
          tick_launch(B=128, K=512, N=256, n_read=4, dtypes=tick_dt,
                      has_c=True, has_delays=True, has_drive=True,
                      write_delay=True, n_full=4)),
+        # The serving path's slot grid: 4 slots, each at its own pointers;
+        # every operand stacked per slot but the mask, which the slots
+        # share.
+        ("tick_fused/slots",
+         tick_launch(B=8, K=512, N=256, n_read=1, dtypes=tick_dt,
+                     has_c=True, has_delays=False, has_drive=True,
+                     write_delay=False, n_slots=4,
+                     batched=("dly_read", "w", "v", "r", "drive", "v_th",
+                              "leak", "r_ref", "gain", "i_bias",
+                              "v_reset"))),
         ("event_dispatch_db",
          event_db_launch(B=8, K=1024, N=256, k_active=128, dtypes=ev_dt,
                          has_drive=True)),

@@ -39,7 +39,9 @@ class Operand:
 
     ``block_shape``/``index_map`` are None for ``memory_space="any"``
     operands (no automatic pipelining -- the kernel issues its own DMAs,
-    described by :attr:`KernelLaunch.dma_schedule`).
+    described by :attr:`KernelLaunch.dma_schedule`).  A None entry of
+    ``block_shape`` is a dimension of one, squeezed out of the kernel's
+    view of the block.
     """
 
     name: str
@@ -65,7 +67,7 @@ class Operand:
     def block_bytes(self) -> int:
         if self.block_shape is None:
             return 0   # HBM-resident; manual DMAs are scratch-accounted
-        return (math.prod(self.block_shape)
+        return (math.prod(b for b in self.block_shape if b is not None)
                 * np.dtype(self.dtype).itemsize)
 
 

@@ -168,7 +168,9 @@ def fused_tick(
     write -- replacing the 4-op chain of the split backends (see
     :mod:`repro.kernels.tick_fused`). The circular read/write pointers
     ``tick % D`` / ``(tick+1) % D`` ride in as scalar-prefetch operands,
-    so advancing the tick never retraces.
+    so advancing the tick never retraces. Under ``vmap`` over slots (the
+    serving path) the kernel runs once for all slots, on its slot grid,
+    and reads each slot's operands in place (see :func:`_slot_batchable`).
 
     Args:
       wc: pre-masked ``W*C`` (frozen path, hoisted by the caller as a
@@ -245,17 +247,44 @@ def fused_tick(
     ibias_p = _pad_to(params.lif.i_bias, 0, bn)
     vreset_p = _pad_to(params.lif.v_reset, 0, bn)
 
-    v_new, r_new, y, dly_new = _tick_kernel.fused_tick(
+    v_new, r_new, y, dly_new = _slot_batchable(functools.partial(
+        _tick_kernel.fused_tick, mode=mode, block_b=bb, block_n=bn,
+        block_k=bk, interpret=interpret))(
         slots, read_p, w_p, c_p, delays_p, v_p, r_p, drive_p, dly_full_p,
-        vth_p, leak_p, rref_p, gain_p, ibias_p, vreset_p,
-        mode=mode, block_b=bb, block_n=bn, block_k=bk, interpret=interpret,
-    )
+        vth_p, leak_p, rref_p, gain_p, ibias_p, vreset_p)
     unflat = lambda a: a[:B, :n].reshape(batch_shape + (n,))
     lif = LIFState(v=unflat(v_new), r=unflat(r_new), y=unflat(y))
     if not write:
         return lif, st.delay_buf
     delay_buf = dly_new[:B, :, :n].reshape(batch_shape + (max_delay, n))
     return lif, delay_buf
+
+
+def _slot_batchable(kernel):
+    """``kernel`` (the whole-tick kernel with its static arguments bound)
+    as a function whose ``vmap`` is the kernel's slot grid.
+
+    Under the serving path's slot ``vmap`` each slot has its own tick, so
+    the pointer table is batched; JAX batches such a ``pallas_call`` as a
+    loop over the slots that slices each slot's whole ``W`` and ``C`` out
+    of the stack and writes the outputs back. Here the batched call is
+    one launch instead: the pointers go in as an ``(S, 2)`` table, the
+    stacked operands stay stacked and are read in place, and the
+    unbatched ones are shared by every slot. Unbatched calls run the
+    kernel as it is."""
+
+    @jax.custom_batching.custom_vmap
+    def call(slots, *args):
+        return kernel(slots, *args)
+
+    @call.def_vmap
+    def _slot_grid(n_slots, in_batched, slots, *args):
+        if not in_batched[0]:
+            slots = jnp.broadcast_to(slots, (n_slots,) + slots.shape)
+        out = kernel(slots, *args)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return call
 
 
 def fused_stdp_step(

@@ -39,6 +39,14 @@ axis, K-steps accumulating into a VMEM f32 scratch):
   fresh spikes are stored into write slot ``slots_ref[1] = (tick+1) % D``
   of the output delay buffer (the other ``D-1`` slots stream through
   unchanged from the input tile).
+* **Slot grid.** Serving runs S resident networks, each at its own
+  tick. Given an ``(S, 2)`` table of pointers, the kernel takes a leading
+  slot grid axis (grid ``(S, B/bB, N/bN, K/bK)``): an operand with a
+  leading slot axis is read tile by tile from slot ``s`` of its stacked
+  array, one without it is shared by every slot, and slot ``s`` reads
+  its pointers from row ``s`` of the table. JAX's own batching of a
+  kernel whose scalar prefetch is batched is a loop of calls that
+  copies each slot's whole operands out of the stack first.
 
 All shapes must be pre-padded to block multiples by the caller
 (:func:`repro.kernels.ops.fused_tick` handles padding, slot scalars,
@@ -46,8 +54,9 @@ and the state-dataclass bridge).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Collection, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -65,21 +74,24 @@ DEFAULT_BLOCK_K = 512
 
 
 def _tick_kernel(
-    slots_ref,          # (2,) i32 in SMEM: [read_slot, write_slot]
-    *refs,
+    slots_ref,          # (2,) i32 in SMEM: [read_slot, write_slot]; (S, 2)
+    *refs,              # on the slot grid, one row per slot
     mode: str,
     n_delay: int,
     has_c: bool,
     has_delays: bool,
     has_drive: bool,
     write_delay: bool,
+    slot_grid: bool,
 ):
     """One grid step of the whole-tick circuit.
 
     ``refs`` carries, in order: the variable-presence inputs
     (``dly_read, w, [c], [delays], v, r, [drive], [dly_full]``), the six
     per-neuron parameter rows, the outputs (``v', r', y', [dly']``), and
-    the f32 accumulator scratch.
+    the f32 accumulator scratch. On the slot grid (``slot_grid``) the
+    slot axis is squeezed out of every block, so the refs are a slot's
+    tiles.
     """
     it = iter(refs)
     dly_read_ref = next(it)
@@ -96,8 +108,13 @@ def _tick_kernel(
     dly_out_ref = next(it) if write_delay else None
     acc_ref = next(it)
 
-    k = pl.program_id(2)
-    nk = pl.num_programs(2)
+    if slot_grid:
+        slot_id = pl.program_id(0)
+        pointer = lambda j: slots_ref[slot_id, j]
+    else:
+        pointer = lambda j: slots_ref[j]
+    k = pl.program_id(3 if slot_grid else 2)
+    nk = pl.num_programs(3 if slot_grid else 2)
 
     @pl.when(k == 0)
     def _init():
@@ -117,7 +134,7 @@ def _tick_kernel(
         # Per-synapse delays: synapse with delay d reads history slot
         # (slot - (d-1)) % D. Build the d-major flattened contraction so the
         # summation order matches the reference einsum exactly.
-        slot = slots_ref[0]
+        slot = pointer(0)
         hist = [
             dly_read_ref[:, pl.ds(jax.lax.rem(slot - d + n_delay, n_delay), 1), :][:, 0, :]
             for d in range(n_delay)
@@ -153,7 +170,7 @@ def _tick_kernel(
             # other D-1 slots stream through from the input tile unchanged.
             buf = dly_full_ref[...]
             dly_out_ref[...] = buf
-            dly_out_ref[:, pl.ds(slots_ref[1], 1), :] = (
+            dly_out_ref[:, pl.ds(pointer(1), 1), :] = (
                 y[:, None, :].astype(dly_out_ref.dtype))
 
 
@@ -169,6 +186,8 @@ def tick_launch(
     has_drive: bool,
     write_delay: bool,
     n_full: int = 0,
+    n_slots: int = 0,
+    batched: Collection[str] = (),
     block_b: int = DEFAULT_BLOCK_B,
     block_n: int = DEFAULT_BLOCK_N,
     block_k: int = DEFAULT_BLOCK_K,
@@ -182,6 +201,11 @@ def tick_launch(
     it.  ``dtypes`` maps operand names (``dly_read, w, c, delays, v, r,
     drive, dly_full, param``) to dtypes; ``n_full`` is the full delay
     depth D when ``write_delay``.
+
+    ``n_slots > 0`` gives the slot grid: a leading grid axis of that
+    length, an ``(n_slots, 2)`` pointer table, and a leading slot axis on
+    the outputs and on each input named in ``batched`` (the parameter
+    rows by their own names); the other inputs are shared by every slot.
     """
     grid = (B // block_b, N // block_n, K // block_k)
     bn = (block_b, block_n)
@@ -230,6 +254,12 @@ def tick_launch(
     # history index, write slot at the deepest buffer index.
     slots_ex = np.array(
         [n_read - 1, (n_full - 1) if write_delay else 0], np.int32)
+    if n_slots:
+        grid = (n_slots,) + grid
+        inputs = [_on_slot_grid(op, n_slots, op.name in batched)
+                  for op in inputs]
+        outputs = [_on_slot_grid(op, n_slots, True) for op in outputs]
+        slots_ex = np.broadcast_to(slots_ex, (n_slots, 2))
     return KernelLaunch(
         name="tick_fused",
         grid=grid,
@@ -239,6 +269,20 @@ def tick_launch(
         num_scalar_prefetch=1,
         prefetch_example=(slots_ex,),
     )
+
+
+def _on_slot_grid(op: Operand, n_slots: int, stacked: bool) -> Operand:
+    """``op`` under a leading slot grid axis ``s``: a stacked operand
+    gains a leading slot axis whose block is squeezed and indexed by
+    ``s``; a shared one keeps its blocks and ignores ``s``."""
+    index_map = op.index_map
+    if not stacked:
+        return dataclasses.replace(
+            op, index_map=lambda s, *idx: index_map(*idx))
+    return dataclasses.replace(
+        op, shape=(n_slots,) + op.shape,
+        block_shape=(None,) + op.block_shape,
+        index_map=lambda s, *idx: (s,) + index_map(*idx))
 
 
 @functools.partial(
@@ -284,10 +328,15 @@ def fused_tick(
       the tick does not write the delay line (``max_delay == 1``).
     * per-neuron params: (N,), reshaped to (1, N) rows.
 
+    Slot grid: with ``slots`` an ``(S, 2)`` table, one launch runs S
+    ticks, slot ``s`` at pointers ``slots[s]``. Any other operand may then
+    carry a leading slot axis of length S (one array per slot) or not
+    (shared by every slot), and every output carries one.
+
     Returns ``(v', r', y', dly')`` with ``dly'`` None iff ``dly_full`` is.
     """
-    B, n_read, K = dly_read.shape
-    N = w.shape[1]
+    B, n_read, K = dly_read.shape[-3:]
+    N = w.shape[-1]
     if B % block_b or N % block_n or K % block_k:
         raise ValueError(
             f"shapes must be block-aligned: B={B}%{block_b}, "
@@ -299,8 +348,18 @@ def fused_tick(
     has_drive = drive is not None
     write_delay = dly_full is not None
     n_delay = n_read
+    slot_grid = slots.ndim == 2
 
-    row = lambda a: a.reshape(1, N)
+    params = {"v_th": v_th, "leak": leak, "r_ref": r_ref, "gain": gain,
+              "i_bias": i_bias, "v_reset": v_reset}
+    arrays = {"dly_read": dly_read, "w": w, "c": c, "delays": delays,
+              "v": v, "r": r, "drive": drive, "dly_full": dly_full}
+    # An operand stacked over slots has one axis more than its own rank.
+    rank = {"dly_read": 3, "dly_full": 3, **{n: 1 for n in params}}
+    batched = [n for n, a in {**arrays, **params}.items()
+               if a is not None and a.ndim > rank.get(n, 2)]
+    arrays.update({n: a.reshape(a.shape[:-1] + (1, N))
+                   for n, a in params.items()})
     launch = tick_launch(
         B=B, K=K, N=N, n_read=n_read,
         dtypes={"dly_read": dly_read.dtype, "w": w.dtype,
@@ -312,23 +371,21 @@ def fused_tick(
                 "param": v_th.dtype},
         has_c=has_c, has_delays=has_delays, has_drive=has_drive,
         write_delay=write_delay,
-        n_full=dly_full.shape[1] if write_delay else 0,
+        n_full=dly_full.shape[-2] if write_delay else 0,
+        n_slots=slots.shape[0] if slot_grid else 0, batched=batched,
         block_b=block_b, block_n=block_n, block_k=block_k)
-    arrays = {"dly_read": dly_read, "w": w, "c": c, "delays": delays,
-              "v": v, "r": r, "drive": drive, "dly_full": dly_full,
-              "v_th": row(v_th), "leak": row(leak), "r_ref": row(r_ref),
-              "gain": row(gain), "i_bias": row(i_bias),
-              "v_reset": row(v_reset)}
 
     kernel = functools.partial(
         _tick_kernel, mode=mode, n_delay=n_delay, has_c=has_c,
-        has_delays=has_delays, has_drive=has_drive, write_delay=write_delay)
+        has_delays=has_delays, has_drive=has_drive, write_delay=write_delay,
+        slot_grid=slot_grid)
     out = pl.pallas_call(
         kernel,
         grid_spec=launch.grid_spec(),
         out_shape=launch.out_shapes(),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel",) * (len(launch.grid) - 1)
+            + ("arbitrary",),
         ),
         interpret=interpret,
     )(slots.astype(jnp.int32), *launch.gather(arrays))

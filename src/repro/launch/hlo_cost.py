@@ -272,6 +272,29 @@ def mosaic_kernels(hlo: str) -> Dict[str, int]:
     return dict(out)
 
 
+def dynamic_slices(hlo: str, trailing: Tuple[int, ...]
+                   ) -> List[Tuple[int, str]]:
+    """``(bytes, op_name)`` of each ``dynamic-slice`` instruction in a
+    compiled program's text whose result's trailing dimensions are
+    ``trailing`` -- e.g. ``(n, n)`` finds every copy of a whole ``(n, n)``
+    matrix sliced out of a stack. Instructions are counted once each,
+    not per loop trip."""
+    out = []
+    for line in hlo.splitlines():
+        m = _OP_RE.match(line)
+        if not m:
+            continue
+        type_str, rest = _split_type_and_rest(m.group(2))
+        if not rest.startswith("dynamic-slice("):
+            continue
+        (shape,) = _parse_shapes(type_str)
+        if shape[1][-len(trailing):] != tuple(trailing):
+            continue
+        op_name = _OP_NAME_RE.search(line)
+        out.append((_nbytes(shape), op_name.group(1) if op_name else ""))
+    return out
+
+
 def analyze(hlo: str) -> CostSummary:
     """Whole-program cost with while-body trip-count multipliers."""
     comps, entry = parse(hlo)

@@ -152,3 +152,25 @@ class TestMosaicKernels:
     def test_cpu_program_has_none(self):
         text = jax.jit(lambda x: x * 2).lower(jnp.ones(4)).compile().as_text()
         assert hlo_cost.mosaic_kernels(text) == {}
+
+
+class TestDynamicSlices:
+    def test_whole_matrices_by_trailing_dims(self):
+        """Slices are matched by their result's trailing dimensions and
+        carry their op_name; other instructions are not slices."""
+        hlo = "\n".join([
+            '  %ds.1 = f32[1,64,64]{2,1,0:T(8,128)} dynamic-slice(%p.1, %i, '
+            '%z, %z), dynamic_slice_sizes={1,64,64}, metadata={op_name='
+            '"jit(f)/while/body/jit(fused_tick)/while/body/dynamic_slice"}',
+            '  %ds.2 = s32[64,64]{1,0} dynamic-slice(%p.2, %z, %z), '
+            'dynamic_slice_sizes={64,64}',
+            '  %ds.3 = f32[1,64,32]{2,1,0} dynamic-slice(%p.1, %i, %z, %z), '
+            'dynamic_slice_sizes={1,64,32}',
+            '  %dus.4 = f32[4,64,64]{2,1,0} dynamic-update-slice(%p.1, '
+            '%ds.1, %i, %z, %z)',
+        ])
+        assert hlo_cost.dynamic_slices(hlo, (64, 64)) == [
+            (64 * 64 * 4,
+             "jit(f)/while/body/jit(fused_tick)/while/body/dynamic_slice"),
+            (64 * 64 * 4, "")]
+        assert hlo_cost.dynamic_slices(hlo, (64, 32)) == [(64 * 32 * 4, "")]

@@ -438,7 +438,8 @@ def test_existing_chunk_programs_compile_to_the_same_hlo():
     program (pallas_fused over slots, telemetry and STDP) -- compile to
     the same optimized HLO as before ``psc_exp``, ``fan_out`` and the
     Poisson drive existed (digests recorded from that tree on this CPU
-    build of jax)."""
+    build of jax; the dense chunk program's since the whole-tick kernel
+    runs on its slot grid, a deliberate change of that program)."""
     import functools
 
     from repro.launch.serve import SNNServer
@@ -481,4 +482,4 @@ def test_existing_chunk_programs_compile_to_the_same_hlo():
 HLO_PINS = {"jnp/tel0": "8176f75791b60a46", "jnp/tel1": "8e95f110f1f7e302",
             "event/tel0": "e9512b91ee44f375",
             "event/tel1": "35f7d6765dadaf45",
-            "server_chunk/pallas_fused": "d05ecf33948ae015"}
+            "server_chunk/pallas_fused": "c3e7a39ea40c4ea3"}

@@ -138,9 +138,13 @@ class TestLearningOnlyWhereItLearns:
     ``learning_rollout`` with ``learn_until=budget``, and is the
     oracle."""
 
-    @pytest.mark.parametrize("case", sorted(_LEARNING_CASES))
-    def test_bit_exact_vs_wave(self, case):
-        sr, sc, _ = _twin_servers()
+    @pytest.mark.parametrize("case,backend", [
+        *(pytest.param(c, "jnp", id=c) for c in sorted(_LEARNING_CASES)),
+        # The dense tenants on the whole-tick kernel's slot grid.
+        pytest.param("plastic_and_frozen_mixed", "pallas_fused",
+                     id="plastic_and_frozen_mixed-pallas_fused")])
+    def test_bit_exact_vs_wave(self, case, backend):
+        sr, sc, _ = _twin_servers(backend=backend)
         spec = _LEARNING_CASES[case]
         reqs_r = _requests(sr, spec, seed=7)
         reqs_c = _requests(sc, spec, seed=7)

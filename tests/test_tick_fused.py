@@ -1,6 +1,6 @@
 """Whole-tick megakernel (`kernels/tick_fused.py`): parity + recompile pins.
 
-Four pins:
+Five pins:
 
 * **Parity vs the jnp reference** on the hard cases: per-synapse delays
   > 1, refractory counters live mid-rollout, learning on/off -- all
@@ -16,6 +16,9 @@ Four pins:
 
 * **Padding is exact**: ragged n exercises every pad path (weights,
   delay ring, per-neuron params) and still matches the reference.
+
+* **The slot grid is the per-slot kernel**: ``vmap`` over slots at
+  different ticks equals one unbatched call per slot, bit for bit.
 
 Kernels run in interpret mode on CPU -- the same kernel body the TPU
 executes.
@@ -162,6 +165,71 @@ class TestFusedParity:
         eng = TickEngine(EngineOptions(backend="pallas_fused", surrogate=True))
         with pytest.raises(ValueError, match="inference-only"):
             eng.tick(SNNState.zeros((), n), p, None)
+
+
+def _slot_states(n_slots, b, n, max_delay, *, seed):
+    """Per-slot states, each at its own tick, mid-rollout: live membranes,
+    refractory counters, spikes and a filled ring."""
+    rng = np.random.default_rng(seed)
+    shape = (n_slots, b, n)
+    lif = dataclasses.replace(
+        SNNState.zeros((n_slots, b), n).lif,
+        v=jnp.asarray(rng.uniform(0, 1, shape), jnp.float32),
+        r=jnp.asarray(rng.integers(0, 3, shape), jnp.int32),
+        y=jnp.asarray(rng.random(shape) < 0.3, jnp.float32))
+    ring = rng.random((n_slots, b, max_delay, n)) < 0.3
+    return SNNState(lif=lif, delay_buf=jnp.asarray(ring, jnp.float32),
+                    tick=jnp.asarray([0, 1, 5, 2][:n_slots], jnp.int32))
+
+
+# (max_delay, per-synapse delays, weight operands): "wc" a pre-masked W*C
+# per slot (frozen), "w,c" a W per slot and a shared mask C (learning),
+# "stacked" every register per slot.
+_SLOT_GRID_CASES = {
+    "max_delay_1": (1, False, "stacked"),
+    "uniform_ring": (3, False, "stacked"),
+    "per_synapse_delays": (3, True, "stacked"),
+    "frozen_wc": (2, False, "wc"),
+    "learning_w_c": (1, False, "w,c"),
+}
+
+
+class TestSlotGrid:
+    @pytest.mark.parametrize("case", sorted(_SLOT_GRID_CASES))
+    def test_vmap_equals_per_slot_calls(self, case):
+        """``vmap`` over 4 slots at different ticks -- the kernel's slot
+        grid -- equals one unbatched call per slot, bit for bit."""
+        max_delay, with_delays, form = _SLOT_GRID_CASES[case]
+        S, b, n = 4, 2, 139
+        stacked = [_params(n, connectivity.sparse_random(n, 0.5, seed=i),
+                           seed=i, v_th=0.8, r_ref=2) for i in range(S)]
+        params = jax.tree.map(lambda *a: jnp.stack(a), *stacked)
+        st = _slot_states(S, b, n, max_delay, seed=len(case))
+        ext = _ext(n, S, (b,), seed=2)
+        rng = np.random.default_rng(3)
+        delays = (jnp.asarray(rng.integers(1, max_delay + 1, (S, n, n)),
+                              jnp.int32) if with_delays else None)
+        shared = stacked[0]
+        wcs = params.w * params.c
+
+        if form == "wc":
+            def tick(s, p, e, wc, d):
+                return ops.fused_tick(s, p, e, wc=wc, delays=d)
+        elif form == "w,c":
+            def tick(s, p, e, wc, d):
+                return ops.fused_tick(
+                    s, dataclasses.replace(shared, w=p.w), e, delays=d)
+        else:
+            def tick(s, p, e, wc, d):
+                return ops.fused_tick(s, p, e, delays=d)
+
+        at = lambda tree, i: jax.tree.map(lambda a: a[i], tree)
+        got = jax.jit(jax.vmap(tick))(st, params, ext, wcs, delays)
+        for i in range(S):
+            want = jax.jit(tick)(at(st, i), at(params, i), ext[i], wcs[i],
+                                 None if delays is None else delays[i])
+            _assert_trees_bitexact(at(got, i), want)
+        assert float(np.asarray(got[0].y).sum()) > 0, "no slot spiked"
 
 
 class TestFusedRecompilePin:

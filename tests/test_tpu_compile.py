@@ -25,6 +25,7 @@ from repro.core.lif import LIFParams
 from repro.core.network_types import SNNParams, SNNState
 from repro.kernels import ops
 from repro.kernels.event_dispatch import event_lif_dispatch_db
+from repro.launch import hlo_cost
 from repro.launch.hlo_cost import mosaic_kernels
 from repro.obs.telemetry import TickTelemetry
 from repro.parallel import snn_sharding
@@ -173,16 +174,12 @@ class TestKernels:
                                                "fused_lif_step": 1}
 
 
-def test_server_chunk_vmapped_over_slots(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def fused_chunk(one_chip):
     """The continuous-serving chunk program of a ``pallas_fused`` server
-    over 8 slots, exactly as :class:`SNNServer` builds it: the whole-tick
-    kernel survives the slot vmap, and the STDP kernel has one call site,
-    in the per-slot learning ``cond`` the slot loop runs."""
+    over 8 slots, exactly as :class:`SNNServer` builds it, compiled."""
     from repro.launch.serve import SNNServer
 
-    # The kernel bridges pick interpret mode off-TPU; this process only
-    # describes the chip, so tell them the program is for one.
-    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     n, slots, chunk = 4096, 8, 8
     server = SNNServer(n_max=n, slots=slots, max_ticks=32,
                        backend="pallas_fused", chunk_ticks=chunk)
@@ -194,13 +191,38 @@ def test_server_chunk_vmapped_over_slots(one_chip, monkeypatch):
     arr = lambda shape, dt=F32: jax.ShapeDtypeStruct(
         lead + shape, dt, sharding=one_chip)
     fn = functools.partial(server._chunk_fn, "pallas_fused", chunk)
-    c = _compile(fn, _spec(params, one_chip, lead), _spec(carry, one_chip, lead),
-                 arr((chunk, n)), arr((n, n)), arr((chunk,)), arr((), I32),
-                 arr((), I32), arr((), jnp.bool_), arr((n,)))
+    # The kernel bridges pick interpret mode off-TPU; this process only
+    # describes the chip, so tell them the program is for one.
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_on_tpu", lambda: True)
+        compiled = _compile(
+            fn, _spec(params, one_chip, lead), _spec(carry, one_chip, lead),
+            arr((chunk, n)), arr((n, n)), arr((chunk,)), arr((), I32),
+            arr((), I32), arr((), jnp.bool_), arr((n,)))
+    return n, compiled
+
+
+def test_server_chunk_vmapped_over_slots(fused_chunk):
+    """The whole-tick kernel survives the slot vmap, and the STDP kernel
+    has one call site, in the per-slot learning ``cond`` the slot loop
+    runs."""
+    _, c = fused_chunk
     # The whole-tick kernel and the STDP pass: both Mosaic kernels.
     assert mosaic_kernels(c.as_text()) == {"fused_tick": 1,
                                            "fused_stdp_step": 1}
     assert c.memory_analysis().argument_size_in_bytes < 16 * 2 ** 30
+
+
+def test_server_chunk_reads_slot_stacks_in_place(fused_chunk):
+    """The whole-tick kernel reads each slot's ``W`` and ``C`` tile by
+    tile from the stacked ``(S, n, n)`` operands, on its slot grid: no
+    whole ``(n, n)`` matrix is sliced out of a stack for it. Only the
+    learning ``cond`` slices one out: the learning slot's, for the STDP
+    pass."""
+    n, c = fused_chunk
+    slices = hlo_cost.dynamic_slices(c.as_text(), (n, n))
+    assert not [s for s in slices if "jit(fused_tick)" in s[1]]
+    assert slices and all("/cond/" in op_name for _, op_name in slices)
 
 
 def test_sharded_tick_on_four_chips(topo):
